@@ -90,7 +90,7 @@ def kernels():
     flops = 4 * b * h * s * s * hd
     rows.append(("kernel/flash_attention/ref_cpu",
                  f"{t * 1e3:.1f}ms for {flops / 1e9:.1f}GF",
-                 f"tpu_roofline={flops / hw.TPU_PEAK_FLOPS_BF16 * 1e6:.1f}us"))
+                 f"tpu_roofline={flops / hw.TPU_V5E.peak_flops * 1e6:.1f}us"))
 
     from repro.kernels.ssd_scan import ops as ssd
     b2, s2, h2, p2, n2 = 2, 512, 8, 64, 64
@@ -107,7 +107,7 @@ def kernels():
                                     + 2 * chunk * chunk * p2)
     rows.append(("kernel/ssd_scan/ref_cpu",
                  f"{t * 1e3:.1f}ms for {fl / 1e9:.1f}GF intra-chunk",
-                 f"tpu_roofline={fl / hw.TPU_PEAK_FLOPS_BF16 * 1e6:.1f}us"))
+                 f"tpu_roofline={fl / hw.TPU_V5E.peak_flops * 1e6:.1f}us"))
 
     # the two tuned kernels: full roofline-pruned measured search at the
     # benchmark shapes, plus the untimed interpret-parity checks

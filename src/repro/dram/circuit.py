@@ -64,6 +64,18 @@ TABLE3_PUBLISHED = {
 SIGNAL_INTEGRITY_FLOOR = 0.90
 
 
+def _on_host(fn):
+    """Evaluate ``fn`` on the host CPU.  The latency model's float32 answers
+    set the characterization thresholds, where ``t_prog / req - 1`` cancels:
+    an accelerator's last-bit ``pow`` error would move them, so they must not
+    depend on which device is attached.  Inside a jit trace it is moot."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_device(jax.local_devices(backend="cpu")[0]):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 def _alpha_power(op: str, v):
     c, a1, vth1, al1, a2, vth2, al2 = ALPHA_POWER[op]
     v = jnp.asarray(v, jnp.float64) if jax.config.read("jax_enable_x64") else jnp.asarray(v, jnp.float32)
@@ -89,6 +101,7 @@ def _ras_raw(v):
     return jnp.where(v < xs[0], lo, jnp.where(v > xs[-1], hi, mid))
 
 
+@_on_host
 def raw_latency(op: str, v_array):
     """Inherent (pre-guardband) latency of one DRAM operation, in ns.
 
@@ -181,6 +194,7 @@ VENDORS = {
 }
 
 
+@_on_host
 def vendor_raw_latency(op: str, v_array, vendor: str, temp_c: float = 20.0,
                        dimm_z: float = 0.0):
     """Raw latency for one vendor's DIMM at a given voltage/temperature.

@@ -21,7 +21,8 @@ rather than throughput.  This module gives every entry point
    bucket, static config) via ``jax.jit(...).lower(...).compile()`` and
    held in an explicit table with hit/compile counters (``stats()``), so
    retrace regressions are testable.  ``enable_persistent_cache()`` points
-   JAX's persistent compilation cache at ``artifacts/jax_cache`` so repeated
+   JAX's persistent compilation cache at ``$JAX_COMPILATION_CACHE_DIR`` (or
+   the checkout's ``artifacts/jax_cache``) so repeated
    ``scripts/check.sh`` / benchmark runs pay XLA compilation once per
    machine.
 3. **Chunked megabatch execution** — a request larger than the biggest
@@ -42,6 +43,15 @@ rather than throughput.  This module gives every entry point
 Both dispatched paths are sliced back to the caller's N and are bit-exact
 per element against the direct (unbucketed) calls, which every entry point
 keeps as its parity reference (``dispatch="direct"``).
+
+On a multi-device mesh both paths run the kernel under ``shard_map`` over
+``"batch"``: every kernel is lane-local, so each device runs it on its own
+slice of lanes and no operand is gathered (GSPMD refuses to partition the
+Pallas custom calls inside the kernels).  Of the direct calls, the Test-1
+/ hammer plane holds a Pallas kernel and runs under the same
+:func:`lane_sharded` wrapper; the characterization, min-latency and
+beat-error kernels are plain jnp, which GSPMD partitions, and the
+controller's direct call runs unsharded on the default device.
 """
 from __future__ import annotations
 
@@ -62,7 +72,11 @@ DEFAULT_MAX_BUCKET = 4096
 # caller's per-element word count): chunk * element_cost <= budget.
 DEFAULT_MAX_ELEMENTS_RESIDENT = 1 << 27
 
-DEFAULT_CACHE_DIR = os.path.join("artifacts", "jax_cache")
+# <checkout>/artifacts/jax_cache, from this file's location (src/repro/
+# engine/dispatch.py), so every working directory shares one cache
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), "artifacts", "jax_cache")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +143,7 @@ def _leaf_key(x):
 def _stats_entry(entry: str) -> dict:
     return _STATS.setdefault(entry, {"calls": 0, "compiles": 0, "hits": 0,
                                      "chunked_calls": 0, "max_resident": 0,
+                                     "compile_us_total": 0.0,
                                      "dispatch_us_total": 0.0,
                                      "dispatch_us_last": 0.0})
 
@@ -138,6 +153,7 @@ def stats(entry: str | None = None) -> dict:
     ``lower().compile()`` invocations = traces) / ``hits`` (warm-executable
     reuses) / ``chunked_calls`` / ``max_resident`` (largest resident flat
     batch actually materialized — the peak-memory proxy) /
+    ``compile_us_total`` (wall time of the ``lower().compile()`` calls) /
     ``dispatch_us_total`` and ``dispatch_us_last`` (blocking wall time of
     the compiled executions, cumulative and most-recent — compile time is
     excluded, so reuse *and* steady latency are separately inspectable).
@@ -167,6 +183,15 @@ def record_gauge(entry: str, **gauges) -> None:
 def reset_stats() -> None:
     with _LOCK:
         _STATS.clear()
+
+
+def executables(entry: str) -> list:
+    """The compiled executables cached for ``entry`` and its chunked form
+    (``compiled.as_text()`` shows what each runs — e.g. whether a Pallas
+    ``tpu_custom_call`` is inside)."""
+    with _LOCK:
+        return [c for k, c in _EXECUTABLES.items()
+                if k[0] in (entry, entry + "/chunked")]
 
 
 def clear_cache() -> None:
@@ -219,14 +244,18 @@ def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
             if compiled is None:
                 jitted = jax.jit(fn, donate_argnums=tuple(range(len(args)))
                                  if donate else ())
+                t0 = time.perf_counter()
                 with warnings.catch_warnings():
                     warnings.filterwarnings(
                         "ignore",
                         message="Some donated buffers were not usable")
                     compiled = jitted.lower(*args).compile()
+                us = (time.perf_counter() - t0) * 1e6
                 with _LOCK:
                     _EXECUTABLES[key] = compiled
-                    _stats_entry(entry)["compiles"] += 1
+                    s = _stats_entry(entry)
+                    s["compiles"] += 1
+                    s["compile_us_total"] += us
             else:
                 with _LOCK:
                     _stats_entry(entry)["hits"] += 1
@@ -249,6 +278,24 @@ def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
 # --------------------------------------------------------------------------
 def _valid_mask(n: int, n_to: int) -> np.ndarray:
     return (np.arange(n_to) < n)
+
+
+def lane_sharded(fn, mesh, n_batched: int, n_replicated: int,
+                  lane_axis: int):
+    """Run ``fn`` per device under ``shard_map``: the batched operands, the
+    lane mask and every output split ``lane_axis`` over ``"batch"``; the
+    replicated operands are whole on every device.  Argument order is the
+    resident kernel's ``(*batched, *replicated, valid)`` for
+    ``lane_axis == 0`` and the chunk stream's ``(*stacked, valid,
+    *replicated)`` for ``lane_axis == 1``."""
+    P = jax.sharding.PartitionSpec
+    lanes = P(*([None] * lane_axis), "batch")
+    if lane_axis == 0:
+        specs = (lanes,) * n_batched + (P(),) * n_replicated + (lanes,)
+    else:
+        specs = (lanes,) * (n_batched + 1) + (P(),) * n_replicated
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=lanes,
+                         check_vma=False)
 
 
 def _chunk_fn(kernel, n_batched: int):
@@ -322,6 +369,8 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
                 jax.device_put(a, mesh_lib.batch_sharding(mesh, a.ndim))
                 for a in args)
         rep = _replicate(replicated, mesh, n_devices)
+        if n_devices > 1:
+            kernel = lane_sharded(kernel, mesh, len(batched), len(rep), 0)
         out = aot_call(entry, kernel, args[:-1] + rep + args[-1:],
                        statics_key=statics_key, resident=resident,
                        config_label=config_label)
@@ -344,7 +393,10 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     rep = _replicate(replicated, mesh, n_devices)
     with _LOCK:
         _stats_entry(entry)["chunked_calls"] += 1
-    out = aot_call(entry + "/chunked", _chunk_fn(kernel, len(stacked)),
+    fn = _chunk_fn(kernel, len(stacked))
+    if n_devices > 1:
+        fn = lane_sharded(fn, mesh, len(stacked), len(rep), 1)
+    out = aot_call(entry + "/chunked", fn,
                    stacked + (valid,) + rep, statics_key=statics_key,
                    donate=True, resident=chunk, config_label=config_label)
     return {key: np.asarray(v).reshape((k * chunk,) + v.shape[2:])[:n]
@@ -363,19 +415,17 @@ def _replicate(replicated, mesh, n_devices: int) -> tuple:
 # --------------------------------------------------------------------------
 # Persistent compilation cache
 # --------------------------------------------------------------------------
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``path`` (default
-    ``artifacts/jax_cache`` or ``$JAX_COMPILATION_CACHE_DIR``), with the
-    size/compile-time thresholds dropped to zero so every engine kernel
-    persists.  Safe to call repeatedly; returns the directory (or None when
-    this jax build has no persistent cache)."""
-    path = path or os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                  DEFAULT_CACHE_DIR)
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except (AttributeError, ValueError, OSError):  # older jax / RO file
-        return None
+def enable_persistent_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set, and otherwise at
+    ``<checkout>/artifacts/jax_cache`` (:data:`DEFAULT_CACHE_DIR`, the same
+    from any working directory), with the size/compile-time thresholds
+    dropped to zero so every engine kernel persists.  Call it before the
+    first compile.  Safe to call repeatedly; returns the directory, and
+    raises when the directory cannot be made or JAX refuses the setting."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path

@@ -40,7 +40,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import hw
 from repro.dram import chips, circuit, timing
@@ -225,12 +224,25 @@ def _ndtr(x):
     return 0.5 * jax.lax.erfc(-x * (1.0 / np.sqrt(2.0)))
 
 
-def _characterize_flat_fn(req_rcd, req_rp, sigma, floor, vmin, v, temp,
-                          field_n, pattern_h, retention_ms, t_rcd,
-                          t_rp, valid):
+def x_threshold32(t_prog, req, sigma) -> np.ndarray:
+    """float32 cell-failure z-thresholds ``(t_prog / req - 1) / sigma``,
+    rounded step for step as ``errors._x_threshold`` (float32 required
+    latency, float32 arithmetic), vectorized over lanes.
+
+    Resolved on the host, never in a kernel: a TPU's float32 division is
+    not correctly rounded, and the cancellation in ``t_prog / req - 1``
+    turns its last-bit error into a threshold error of ~1e-6 relative."""
+    return ((np.asarray(t_prog, np.float32) / np.asarray(req, np.float32)
+             - np.float32(1.0)) / np.asarray(sigma, np.float32))
+
+
+def _characterize_flat_fn(req_rcd, req_rp, x_rcd, x_rp, floor, vmin, v,
+                          temp, field_n, pattern_h, retention_ms, valid):
     """The flat-batch characterization kernel (float64 under x64).
 
     All leading axes are the flattened N = D*V*T grid (sharded);
+    ``x_rcd`` / ``x_rp`` are the lanes' float32 failure thresholds at the
+    programmed latencies (:func:`x_threshold32`, held in float64);
     ``field_n`` [N, FIELD_SIZE] is each element's susceptibility field,
     gathered eagerly at dispatch so the executable shape depends only on
     the flat bucket, never on the DIMM count; ``pattern_h`` [P] and
@@ -247,16 +259,11 @@ def _characterize_flat_fn(req_rcd, req_rp, sigma, floor, vmin, v, temp,
         return jnp.where(x <= -xmax, 0.0, jnp.where(x >= xmax, 1.0, p))
 
     # -- error onset (Fig. 4) + spatial maps (Fig. 8) ----------------------
-    # The scalar path derives the x threshold in float32 (required_latency
-    # is float32 and the threshold arithmetic stays in that dtype — see
-    # errors._x_threshold); mirror that rounding, then evaluate the CDF in
-    # float64 exactly like chips._trunc_phi.
-    sigma32 = sigma.astype(jnp.float32)
+    # the float32 thresholds come rounded from the host; the CDF runs in
+    # float64 exactly like chips._trunc_phi
     p_ok = jnp.ones_like(field_n)
-    for t_prog, req in ((t_rcd, req_rcd), (t_rp, req_rp)):
-        x32 = (t_prog.astype(jnp.float32) / req.astype(jnp.float32)
-               - 1.0) / sigma32                              # [N] f32
-        p_ok = p_ok * trunc_phi(x32.astype(field_n.dtype)[:, None] - field_n)
+    for x in (x_rcd, x_rp):
+        p_ok = p_ok * trunc_phi(x[:, None] - field_n)
     frac = 1.0 - jnp.mean(p_ok, axis=1)
     frac = jnp.where(v < floor, jnp.maximum(frac, 0.5), frac)
     line_map = 1.0 - p_ok
@@ -328,9 +335,13 @@ def characterize_inputs(grid: DimmGrid, v, t_grid, patterns, retention_ms,
     per_d = lambda a: flat(np.asarray(a, np.float64)[:, None, None])
     field64 = grid.susceptibility.reshape(d_, FIELD_SIZE)
     d_idx = flat(np.arange(d_)[:, None, None]).astype(np.int32)
+    sigma = per_d(grid.cell_sigma)
+    req_rcd, req_rp = req["rcd"].reshape(-1), req["rp"].reshape(-1)
     inputs = [
-        req["rcd"].reshape(-1), req["rp"].reshape(-1),
-        per_d(grid.cell_sigma), per_d(grid.fail_floor), per_d(grid.vmin),
+        req_rcd, req_rp,
+        x_threshold32(t_rcd, req_rcd, sigma).astype(np.float64),
+        x_threshold32(t_rp, req_rp, sigma).astype(np.float64),
+        per_d(grid.fail_floor), per_d(grid.vmin),
         flat(np.asarray(v, np.float64)[None, :, None]),
         flat(np.asarray(t_grid, np.float64)[None, None, :]),
         field64[d_idx],     # eager gather: shape depends on N alone, not D
@@ -338,8 +349,7 @@ def characterize_inputs(grid: DimmGrid, v, t_grid, patterns, retention_ms,
     pattern_h = np.array([chips.pattern_phase(p) for p in patterns],
                          np.float64)
     ret = np.asarray(retention_ms, np.float64)
-    replicated = (pattern_h, ret, np.float64(t_rcd), np.float64(t_rp))
-    return inputs, replicated
+    return inputs, (pattern_h, ret)
 
 
 def _characterize_batched(grid, v, t_grid, patterns, retention_ms,
@@ -348,11 +358,11 @@ def _characterize_batched(grid, v, t_grid, patterns, retention_ms,
     d_, v_, t_ = grid.n_dimms, v.size, len(t_grid)
     inputs, replicated = characterize_inputs(grid, v, t_grid, patterns,
                                              retention_ms, t_rcd, t_rp)
-    pattern_h, ret = replicated[0], replicated[1]
+    pattern_h, ret = replicated
 
     mesh = mesh_lib.make_batch_mesh() if mesh is None else mesh
     n_devices = int(mesh.devices.size)
-    with enable_x64():
+    with jax.enable_x64(True):
         if dispatch_mode == "direct":
             inputs, n_pad = _pad_flat(inputs, n_devices)
             args = [jnp.asarray(a) for a in inputs]
@@ -364,8 +374,7 @@ def _characterize_batched(grid, v, t_grid, patterns, retention_ms,
                 valid = jax.device_put(valid,
                                        mesh_lib.batch_sharding(mesh, 1))
             out = _characterize_flat(*args, jnp.asarray(pattern_h),
-                                     jnp.asarray(ret), np.float64(t_rcd),
-                                     np.float64(t_rp), valid)
+                                     jnp.asarray(ret), valid)
             out = {k: np.asarray(a, np.float64) for k, a in out.items()}
             if n_pad:
                 out = {k: a[:-n_pad] for k, a in out.items()}
@@ -394,34 +403,29 @@ def _characterize_batched(grid, v, t_grid, patterns, retention_ms,
 # --------------------------------------------------------------------------
 # Batched beat-error distribution (Fig. 9) — the ECC-admission substrate
 # --------------------------------------------------------------------------
-def _beat_error_flat_fn(req_rcd, req_rp, sigma, floor, vmin, v, t_rcd,
-                        t_rp, field_n, valid):
+def _beat_error_flat_fn(x_rcd, x_rp, floor, vmin, v, field_n, valid):
     """Fig. 9 beat-error classes over the flat N = D*K*T batch (float64
     under x64): the jnp form of ``DIMM.beat_error_distribution``.
 
-    Unlike the characterization kernel, the programmed latencies ``t_rcd``
-    / ``t_rp`` are *per-lane* operands — the ECC admission policy evaluates
-    every candidate at its own table timings (probe timings where the
-    min-latency floor excluded it).  The line-error fraction keeps the
-    scalar path's float32 threshold convention (see
-    ``_characterize_flat_fn``); the binomial beat classes are closed-form
-    powers, so parity with the scipy-pmf scalar reference is to float64
-    round-off, not bit-exact (tests assert ~1e-9 relative).
+    ``x_rcd`` / ``x_rp`` are each lane's float32 failure thresholds
+    (:func:`x_threshold32`) at its *own* programmed latencies — the ECC
+    admission policy evaluates every candidate at its table timings (probe
+    timings where the min-latency floor excluded it).  The binomial beat
+    classes are closed-form powers, so parity with the scipy-pmf scalar
+    reference is to float64 round-off, not bit-exact (tests assert ~1e-9
+    relative).
     """
     xmax = chips.CELL_XMAX
-    lo, hi = _ndtr(-jnp.asarray(xmax, req_rcd.dtype)), \
-        _ndtr(jnp.asarray(xmax, req_rcd.dtype))
+    lo, hi = _ndtr(-jnp.asarray(xmax, x_rcd.dtype)), \
+        _ndtr(jnp.asarray(xmax, x_rcd.dtype))
 
     def trunc_phi(x):
         p = (_ndtr(jnp.clip(x, -xmax, xmax)) - lo) / (hi - lo)
         return jnp.where(x <= -xmax, 0.0, jnp.where(x >= xmax, 1.0, p))
 
-    sigma32 = sigma.astype(jnp.float32)
     p_ok = jnp.ones_like(field_n)
-    for t_prog, req in ((t_rcd, req_rcd), (t_rp, req_rp)):
-        x32 = (t_prog.astype(jnp.float32) / req.astype(jnp.float32)
-               - 1.0) / sigma32                              # [N] f32
-        p_ok = p_ok * trunc_phi(x32.astype(field_n.dtype)[:, None] - field_n)
+    for x in (x_rcd, x_rp):
+        p_ok = p_ok * trunc_phi(x[:, None] - field_n)
     frac = 1.0 - jnp.mean(p_ok, axis=1)
     frac = jnp.where(v < floor, jnp.maximum(frac, 0.5), frac)
 
@@ -467,11 +471,12 @@ def beat_error_inputs(grid: DimmGrid, v, t_rcd, t_rp, t_grid) -> list:
         np.asarray(a, np.float64), (d_, k_))[:, :, None])
     field64 = grid.susceptibility.reshape(d_, FIELD_SIZE)
     d_idx = flat(np.arange(d_)[:, None, None]).astype(np.int32)
+    sigma = per_d(grid.cell_sigma)
+    x = [x_threshold32(per_dk(t_prog), req[op].reshape(-1), sigma)
+         .astype(np.float64) for op, t_prog in (("rcd", t_rcd), ("rp", t_rp))]
     return [
-        req["rcd"].reshape(-1), req["rp"].reshape(-1),
-        per_d(grid.cell_sigma), per_d(grid.fail_floor), per_d(grid.vmin),
+        *x, per_d(grid.fail_floor), per_d(grid.vmin),
         flat(v[None, :, None]),
-        per_dk(t_rcd), per_dk(t_rp),
         field64[d_idx],
     ]
 
@@ -518,7 +523,7 @@ def beat_error_batch(grid: DimmGrid, v, t_rcd=10.0, t_rp=10.0,
     inputs = beat_error_inputs(grid, v, t_rcd, t_rp, t_grid)
     mesh = mesh_lib.make_batch_mesh() if mesh is None else mesh
     n_devices = int(mesh.devices.size)
-    with enable_x64():
+    with jax.enable_x64(True):
         if dispatch == "direct":
             inputs, n_pad = _pad_flat(inputs, n_devices)
             args = [jnp.asarray(a) for a in inputs]

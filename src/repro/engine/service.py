@@ -65,10 +65,10 @@ compatible within an entry point, indifferent to batch composition.
 (one dispatch per request) — the request-at-a-time baseline the coalescing
 path is benchmarked against (``benchmarks/serve_bench.py``).
 
-Threading note: dispatches run on one worker thread (JAX's global
-x64 flag is toggled per entry point, so concurrent engine calls from other
-threads must not race a live service; the single worker serializes the
-service's own dispatches).
+Threading note: dispatches run on one worker thread, which serializes
+the service's own dispatches.  The float64 entry points enter
+``jax.enable_x64(True)`` on that thread; JAX's config contexts are
+thread-local, so callers on other threads keep their own setting.
 """
 from __future__ import annotations
 
@@ -79,7 +79,6 @@ from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import power as power_lib
 from repro.engine import controller
@@ -417,8 +416,7 @@ class EngineService:
     def run_request(self, request, *, mode: str = "auto"):
         """Serve one request synchronously: same lowering, one dispatch —
         the request-at-a-time baseline (and the warm path tests compare
-        the coalesced results against).  Not for use concurrently with a
-        live async stream (the x64 flag is process-global)."""
+        the coalesced results against)."""
         low = self._lower(request)
         out = self._run_dispatch(low.spec, low.resolve(), mode)
         return low.postprocess(out)
@@ -542,7 +540,7 @@ class EngineService:
                 config_label=spec.config_label)
 
         if spec.x64:
-            with enable_x64():
+            with jax.enable_x64(True):
                 return call()
         return call()
 
@@ -608,12 +606,12 @@ class EngineService:
         ret = np.asarray(req.retention_ms, np.float64)
         pattern_h = np.array([population.chips.pattern_phase(p)
                               for p in req.patterns], np.float64)
-        replicated = (pattern_h, ret, np.float64(req.t_rcd),
-                      np.float64(req.t_rp))
+        # the programmed latencies ride the lanes (as their thresholds), so
+        # requests at different latencies still share a megabatch
         spec = _GroupSpec("characterize", population._characterize_flat_fn,
-                          replicated, (), 8 * population.FIELD_SIZE, True)
-        key = ("characterize", tuple(req.patterns), ret.tobytes(),
-               float(req.t_rcd), float(req.t_rp))
+                          (pattern_h, ret), (), 8 * population.FIELD_SIZE,
+                          True)
+        key = ("characterize", tuple(req.patterns), ret.tobytes())
         v_, t_ = v.size, len(t_grid)
 
         def resolve():

@@ -49,7 +49,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import hw
 from repro.dram import chips, circuit, errors
@@ -224,7 +223,13 @@ def _dispatch_test1_plane(entry, inputs, patterns, statics, mesh,
             valid = jax.device_put(valid, mesh_lib.batch_sharding(mesh, 1))
             pat = jax.device_put(pat, jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec()))
-        out = _test1_flat(*args, pat, valid, **statics)
+            # GSPMD cannot partition the Pallas inject: one slice per device
+            kernel = dispatch_lib.lane_sharded(
+                functools.partial(_test1_flat_fn, **statics), mesh,
+                len(args), 1, 0)
+            out = jax.jit(kernel)(*args, pat, valid)
+        else:
+            out = _test1_flat(*args, pat, valid, **statics)
         out = {k: np.asarray(a) for k, a in out.items()}
         if n_pad:
             out = {k: a[:-n_pad] for k, a in out.items()}
@@ -647,7 +652,7 @@ def find_min_latency_batch(grid: DimmGrid, v_grid, *, step: float = 2.5,
     n_devices = int(mesh.devices.size)
     # float64 end to end (like characterize_batch): the scalar decision is
     # made on float64 thresholds, so the batched one must not round to f32
-    with enable_x64():
+    with jax.enable_x64(True):
         if dispatch == "direct":
             inputs, n_pad = population._pad_flat(inputs, n_devices)
             args = [jnp.asarray(a) for a in inputs]

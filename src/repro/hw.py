@@ -4,23 +4,18 @@
    controller at 800 MT/s.  These constants parameterize the characterization
    substrate (`repro.dram`) and the Ramulator-style simulator (`repro.memsim`).
 
-2. The deployment domain: a TPU v5e-class pod (the dry-run / roofline
-   target).  These constants parameterize `repro.roofline` and the Voltron
-   HBM adaptation layer (`repro.core.hbm_adapter`).
+2. The deployment domain: TPU chips, keyed by JAX ``device_kind``, with a
+   TPU v5e pod as the dry-run / roofline target.  These constants
+   parameterize `repro.roofline` and the Voltron HBM adaptation layer
+   (`repro.core.hbm_adapter`).
 """
 from __future__ import annotations
 
 import dataclasses
 
 # --------------------------------------------------------------------------
-# TPU v5e-class chip (roofline target; see system brief)
+# TPU deployment target (per-chip peaks: DEVICE_PEAKS below)
 # --------------------------------------------------------------------------
-TPU_PEAK_FLOPS_BF16 = 197e12     # FLOP/s per chip
-TPU_HBM_BW = 819e9               # bytes/s per chip
-TPU_ICI_BW = 50e9                # bytes/s per link
-TPU_HBM_BYTES = 16 * 1024**3     # 16 GiB HBM per chip
-TPU_VMEM_BYTES = 128 * 1024**2   # ~128 MiB VMEM per chip (v5e-class)
-
 # Mesh shape of the production target.
 PODS = 2
 POD_SHAPE = (16, 16)             # (data, model) within one pod
@@ -89,14 +84,36 @@ ARRAY_POWER_FRACTION = 0.60      # fraction of DRAM power in the array domain
 class TpuSpec:
     """Roofline constants for one accelerator chip."""
 
-    peak_flops: float = TPU_PEAK_FLOPS_BF16
-    hbm_bw: float = TPU_HBM_BW
-    ici_bw: float = TPU_ICI_BW
-    hbm_bytes: int = TPU_HBM_BYTES
-    vmem_bytes: int = TPU_VMEM_BYTES
+    peak_flops: float               # FLOP/s per chip (bf16)
+    hbm_bw: float                   # bytes/s per chip
+    ici_bw: float                   # bytes/s per link
+    hbm_bytes: int
+    vmem_bytes: int
 
 
-TPU_V5E = TpuSpec()
+# Published per-chip peaks, keyed by ``jax.devices()[i].device_kind``.
+# "TPU v5 lite" is TPU v5e — Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (4 links of 50 GB/s), 128 MiB of VMEM.
+DEVICE_PEAKS = {
+    "TPU v5 lite": TpuSpec(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                           hbm_bytes=16 * 1024**3,
+                           vmem_bytes=128 * 1024**2),
+}
+
+# The dry-run / roofline target chip.
+TPU_V5E = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_spec(device_kind: str) -> TpuSpec:
+    """Peaks of the chip JAX reports as ``device_kind``; a kind missing
+    from :data:`DEVICE_PEAKS` is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       " add it to hw.DEVICE_PEAKS with its source") from None
+
 
 # Rough development-host CPU spec for the kernel autotuner's roofline
 # pruning when no accelerator is attached (~a few AVX cores + dual-channel

@@ -455,7 +455,8 @@ def tune_kernel(kernel: str, shape, *, candidates=None, smoke: bool = False,
     """
     backend = jax.default_backend()
     if spec is None:
-        spec = hw.TPU_V5E if backend in ("tpu", "gpu") else hw.HOST_CPU
+        spec = (hw.HOST_CPU if backend == "cpu"
+                else hw.device_spec(jax.devices()[0].device_kind))
     from repro.roofline import analyze
     args = _tuning_inputs(kernel, shape, nplanes)
     default = DEFAULTS[kernel]

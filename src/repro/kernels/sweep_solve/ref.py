@@ -86,8 +86,10 @@ def solve_ref(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
         return (new_ipc, loaded, util), None
 
     # ``unroll`` is an autotuner knob (repro.kernels.autotune): it changes
-    # only how XLA lowers the loop, never the step sequence, so every
-    # unroll factor is bit-identical to unroll=1 (today's behavior).
+    # only how XLA lowers the loop, never the step sequence.  While the
+    # loop keeps two or more trips the result is bit-identical to
+    # unroll=1; at one trip XLA inlines the loop, fuses across steps and
+    # the float32 results can move by a few ulps.
     init = (ipc_base, jnp.zeros_like(svc), jnp.zeros_like(svc))
     (ipc, loaded, util), _ = jax.lax.scan(step, init, None, length=iters,
                                           unroll=max(1, int(unroll)))

@@ -29,9 +29,12 @@ WORD_BLOCK = 1024
 def _inject_kernel(nplanes: int, data_ref, prob_ref, rand_ref, planes_ref,
                    out_ref):
     data = data_ref[...]
-    prob = prob_ref[...]                       # [ROW_BLOCK]
-    u = (rand_ref[...] >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
-    bad = (u < prob[:, None]).astype(jnp.uint32)
+    prob = prob_ref[...]                       # [ROW_BLOCK, 1]
+    # Mosaic casts no uint32 to float32; the top 24 bits fit int32 exactly,
+    # so going through int32 gives the oracle's float bit for bit.
+    top = (rand_ref[...] >> jnp.uint32(8)).astype(jnp.int32)
+    u = top.astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    bad = (u < prob).astype(jnp.uint32)
     flip = planes_ref[0]
     for i in range(1, nplanes):
         flip = flip & planes_ref[i]
@@ -48,16 +51,19 @@ def inject_pallas(data, row_prob, rand_word, rand_planes, *, interpret=False,
         raise ValueError(f"shape {(r, w)} must tile by "
                          f"({row_block}, {word_block})")
     grid = (r // row_block, w // word_block)
+    # Mosaic tiles a rank-1 block only at the array's full length or a
+    # multiple of 128, so the per-row probabilities ride as a [rows, 1]
+    # column: a (row_block, 1) block spans the array's whole last axis.
     return pl.pallas_call(
         functools.partial(_inject_kernel, p),
         grid=grid,
         in_specs=[
             pl.BlockSpec((row_block, word_block), lambda i, j: (i, j)),
-            pl.BlockSpec((row_block,), lambda i, j: (i,)),
+            pl.BlockSpec((row_block, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((row_block, word_block), lambda i, j: (i, j)),
             pl.BlockSpec((p, row_block, word_block), lambda i, j: (0, i, j)),
         ],
         out_specs=pl.BlockSpec((row_block, word_block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint32),
         interpret=interpret,
-    )(data, row_prob, rand_word, rand_planes)
+    )(data, row_prob.reshape(r, 1), rand_word, rand_planes)

@@ -1,5 +1,6 @@
 import os
 import sys
+import tomllib
 
 # Tests run on the default single CPU device (the dry-run subprocesses set
 # their own XLA_FLAGS); keep JAX quiet and deterministic.
@@ -13,38 +14,12 @@ _PYPROJECT = os.path.join(os.path.dirname(__file__), "..", "pyproject.toml")
 
 
 def _hypothesis_config() -> dict:
-    """The [tool.repro.hypothesis] table from pyproject.toml.
-
-    tomllib only landed in 3.11; on older interpreters fall back to a
-    line-level parse (the table is flat ``key = scalar`` pairs).
-    """
+    """The [tool.repro.hypothesis] table from pyproject.toml."""
     defaults = {"profile": "repro-ci", "seed": 20260808,
                 "max_examples": 10, "derandomize": True, "print_blob": True}
-    try:
-        import tomllib
-        with open(_PYPROJECT, "rb") as f:
-            table = tomllib.load(f).get("tool", {}).get("repro", {}) \
-                                   .get("hypothesis", {})
-    except (ImportError, OSError):
-        table = {}
-        in_section = False
-        try:
-            with open(_PYPROJECT) as f:
-                for line in f:
-                    line = line.split("#", 1)[0].strip()
-                    if line.startswith("["):
-                        in_section = line == "[tool.repro.hypothesis]"
-                        continue
-                    if in_section and "=" in line:
-                        k, v = (s.strip() for s in line.split("=", 1))
-                        if v in ("true", "false"):
-                            table[k] = v == "true"
-                        elif v.lstrip("-").isdigit():
-                            table[k] = int(v)
-                        else:
-                            table[k] = v.strip("\"'")
-        except OSError:
-            pass
+    with open(_PYPROJECT, "rb") as f:
+        table = tomllib.load(f).get("tool", {}).get("repro", {}) \
+                               .get("hypothesis", {})
     defaults.update(table)
     return defaults
 

@@ -7,9 +7,11 @@ The contract under test (see ``repro.kernels.autotune``):
 - every ``voltage_inject`` config (Pallas blocks, oracle chunks) is
   bit-exact on random non-tile-aligned geometries — the math is integer
   elementwise, so no config may change a single bit;
-- ``sweep_solve`` oracle variants (scan unroll, batch chunking) stay
-  within the suite-wide relative 1e-6 of the default oracle, and pure
-  unroll changes are bit-exact;
+- ``sweep_solve`` oracle variants (scan unroll, batch chunking) change
+  only XLA's fusion boundaries, never the step math: an unroll that keeps
+  a loop of two or more trips is bit-exact, while a loop XLA inlines (an
+  unroll over half the trip count) or the ``lax.map`` of a batch chunk
+  moves each output by at most a few float32 ulps (relative 1e-6);
 - candidates failing parity (or failing to build) are ``ineligible`` and
   can never win; candidates whose padded-traffic roofline bound cannot
   beat the incumbent are ``pruned`` unmeasured;
@@ -31,6 +33,7 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro.kernels import autotune
 from repro.kernels.sweep_solve import kernel as ss_kernel
 from repro.kernels.sweep_solve import ops as ss_ops
+from repro.kernels.sweep_solve import ref as ss_ref
 from repro.kernels.voltage_inject import kernel as vi_kernel
 from repro.kernels.voltage_inject import ops as vi_ops
 
@@ -85,8 +88,42 @@ class TestInjectConfigParity:
             f"{(rows, words)}"
 
 
+# float32 units in the last place an oracle variant may move an output by
+MAX_ULPS = 4
+
+
+def _ulps(got, ref) -> int:
+    """Largest distance between two same-signed float32 arrays, in ulps."""
+    g = np.asarray(got, np.float32).view(np.int32).astype(np.int64)
+    r = np.asarray(ref, np.float32).view(np.int32).astype(np.int64)
+    return int(np.abs(g - r).max())
+
+
+def _assert_within_ulps(got: dict, ref: dict, label: str) -> None:
+    for k in ref:
+        g, r = np.asarray(got[k]), np.asarray(ref[k])
+        if k == "stall_frac":
+            # 1 - ipc / ipc_base cancels: the ratio's ulps (at most those
+            # of 1.0) land as absolute error on a value near zero
+            np.testing.assert_allclose(
+                g, r, rtol=0, atol=MAX_ULPS * np.finfo(np.float32).eps,
+                err_msg=f"{k} @ {label}")
+        else:
+            assert _ulps(g, r) <= MAX_ULPS, (k, label, _ulps(g, r))
+            np.testing.assert_allclose(g, r, rtol=1e-6,
+                                       err_msg=f"{k} @ {label}")
+
+
 class TestSolveConfigParity:
-    """sweep_solve oracle variants vs the default oracle."""
+    """sweep_solve oracle variants vs the default oracle.
+
+    Neither knob touches the step math; both move XLA's fusion boundaries.
+    ``lax.scan`` runs ``iters // unroll`` trips of the unrolled body: while
+    that is two or more, the loop body is the same fused step and the
+    results are bit-exact.  At one trip XLA inlines the loop and fuses
+    across steps; ``oracle_chunk`` wraps the whole solve in a ``lax.map``
+    (every chunk size gives the same bits).  Those move outputs by a few
+    ulps."""
 
     @settings(max_examples=8, deadline=None)
     @given(b=st.integers(min_value=1, max_value=40),
@@ -99,22 +136,31 @@ class TestSolveConfigParity:
         cfg = dataclasses.replace(autotune.DEFAULTS["sweep_solve"],
                                   unroll=unroll, oracle_chunk=chunk)
         got = ss_ops.solve(*args, impl="reference", config=cfg)
-        for k in ref:
-            np.testing.assert_allclose(
-                np.asarray(got[k]), np.asarray(ref[k]), rtol=1e-6,
-                err_msg=f"{k} @ unroll={unroll} chunk={chunk} b={b} c={c}")
+        label = f"unroll={unroll} chunk={chunk} b={b} c={c}"
+        if chunk == 0 and ss_ref.DEFAULT_ITERS // unroll >= 2:
+            for k in ref:
+                assert np.array_equal(np.asarray(got[k]),
+                                      np.asarray(ref[k])), (k, label)
+        else:
+            _assert_within_ulps(got, ref, label)
 
     def test_unroll_alone_is_bit_exact(self):
-        """unroll changes only the loop lowering, never the step math."""
+        """unroll changes only the loop lowering, never the step math: bit
+        exact while the loop keeps two or more trips, within a few ulps
+        once XLA inlines its single trip."""
         args = autotune.solve_inputs(29, 4, seed=5)
         ref = ss_ops.solve(*args, impl="reference")
-        for unroll in (2, 5, 25):
+        iters = ss_ref.DEFAULT_ITERS
+        for unroll in (2, 5, iters // 2, iters // 2 + 1, iters):
             cfg = dataclasses.replace(autotune.DEFAULTS["sweep_solve"],
                                       unroll=unroll)
             got = ss_ops.solve(*args, impl="reference", config=cfg)
-            for k in ref:
-                assert np.array_equal(np.asarray(got[k]),
-                                      np.asarray(ref[k])), (k, unroll)
+            if iters // unroll >= 2:
+                for k in ref:
+                    assert np.array_equal(np.asarray(got[k]),
+                                          np.asarray(ref[k])), (k, unroll)
+            else:
+                _assert_within_ulps(got, ref, f"unroll={unroll}")
 
     def test_interpret_row_block_variant(self):
         args = autotune.solve_inputs(11, 4, seed=9)

@@ -247,15 +247,61 @@ class TestValidation:
                                    [np.zeros((n, 1), np.float32)],
                                    mode="bucketed")
 
-    def test_persistent_cache_round_trips(self, tmp_path):
+    @pytest.fixture
+    def cache_config(self):
+        """Restore JAX's persistent-cache settings (and drop its
+        initialized cache) after a test that points them elsewhere."""
         import jax
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            path = dispatch.enable_persistent_cache(str(tmp_path / "jc"))
-            assert path is not None and os.path.isdir(path)
-            assert jax.config.jax_compilation_cache_dir == path
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before)
+        from jax.experimental.compilation_cache import compilation_cache
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        before = {n: getattr(jax.config, n) for n in names}
+        yield
+        for n, v in before.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
+
+    def test_persistent_cache_round_trips(self, tmp_path, monkeypatch,
+                                          cache_config):
+        import jax
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+        path = dispatch.enable_persistent_cache()
+        assert path == str(tmp_path / "jc") and os.path.isdir(path)
+        assert jax.config.jax_compilation_cache_dir == path
+
+    def test_persistent_cache_writes_only_to_env_dir(self, tmp_path,
+                                                     monkeypatch,
+                                                     cache_config):
+        """With $JAX_COMPILATION_CACHE_DIR set, a compile lands there and
+        nowhere under the working directory."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
+        env_dir, cwd = tmp_path / "env_cache", tmp_path / "cwd"
+        cwd.mkdir()
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+        monkeypatch.chdir(cwd)
+        assert dispatch.enable_persistent_cache() == str(env_dir)
+        compilation_cache.reset_cache()
+        jax.jit(lambda x: jnp.sin(x) * 3.25 + 0.125).lower(
+            jnp.zeros((7, 3))).compile()
+        assert any(env_dir.iterdir())
+        assert not any(cwd.iterdir())
+
+    def test_persistent_cache_default_ignores_cwd(self, tmp_path,
+                                                  monkeypatch, cache_config):
+        """Without the variable the cache is the checkout's
+        artifacts/jax_cache, whatever the working directory."""
+        import jax
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, "artifacts", "jax_cache")
+        for cwd in (tmp_path, repo):
+            monkeypatch.chdir(cwd)
+            assert dispatch.enable_persistent_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        assert not (tmp_path / "artifacts").exists()
 
 
 @pytest.mark.slow

@@ -297,7 +297,8 @@ def test_property_batched_test1_matches_scalar(seed, n, rows, row_bytes,
 def test_multidevice_sharded_test1_matches_scalar():
     """8 forced host devices: the flat D*V*P*R axis (27 elements, not a
     multiple of 8 — exercising the pad path) sharded over a real
-    ("batch",) mesh still matches the scalar loop bit-exactly."""
+    ("batch",) mesh, dispatched and direct, still matches the scalar loop
+    bit-exactly."""
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -315,9 +316,13 @@ def test_multidevice_sharded_test1_matches_scalar():
         v = np.asarray([1.3, 1.15, 1.1])
         mesh = mesh_lib.make_batch_mesh()
         b = engine_test1.run_batch(grid, v, rows=8, mesh=mesh)
+        d = engine_test1.run_batch(grid, v, rows=8, mesh=mesh,
+                                   dispatch="direct")
         s = engine_test1.run_batch(grid, v, rows=8, impl="scalar")
         for f in ("bit_errors", "erroneous_lines", "error_rows"):
             np.testing.assert_array_equal(getattr(b, f), getattr(s, f),
+                                          err_msg=f)
+            np.testing.assert_array_equal(getattr(d, f), getattr(s, f),
                                           err_msg=f)
         fm = engine_test1.find_min_latency_batch(grid, v, mesh=mesh)
         fs = engine_test1.find_min_latency_batch(grid, v, impl="scalar")

@@ -32,10 +32,18 @@ ATOL = 1e-12
 # --------------------------------------------------------------------------
 # Scalar vs batched component parity (property test)
 # --------------------------------------------------------------------------
+def _f32_rate(hi):
+    """Activity rates as the batched path holds them: float32-exact and
+    never subnormal.  A float64 subnormal draw is stored by float32 as 0,
+    which no relative tolerance can compare with the float64 scalar."""
+    return st.floats(0.0, float(np.float32(hi)), width=32,
+                     allow_subnormal=False)
+
+
 class TestComponentParity:
     @given(v_array=st.floats(0.9, 1.35), v_periph=st.floats(1.0, 1.35),
-           freq_ratio=st.floats(0.5, 1.0), acts=st.floats(0.0, 0.05),
-           lines=st.floats(0.0, 0.2),
+           freq_ratio=st.floats(0.5, 1.0), acts=_f32_rate(0.05),
+           lines=_f32_rate(0.2),
            model=st.sampled_from(["ddr3l", "hbm2", "lpddr4"]))
     @settings(max_examples=30)
     def test_scalar_matches_batched(self, v_array, v_periph, freq_ratio,

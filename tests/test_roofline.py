@@ -77,3 +77,12 @@ def test_roofline_terms_and_dominance():
     assert rf.bound_s == 2.0
     assert rf.roofline_fraction == pytest.approx(0.5)
     assert rf.useful_flops_ratio == pytest.approx(0.8)
+
+
+def test_device_peaks_are_keyed_by_device_kind():
+    """The v5e entry is the published chip, and a device kind without an
+    entry raises instead of borrowing another chip's peaks."""
+    assert hw.device_spec("TPU v5 lite") is hw.TPU_V5E
+    assert hw.TPU_V5E.peak_flops == 197e12 and hw.TPU_V5E.hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        hw.device_spec("TPU v4")

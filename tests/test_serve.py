@@ -146,6 +146,21 @@ def test_characterize_coalescing_parity():
         check_parity(req, res)
 
 
+def test_worker_thread_runs_float64_entries_under_x64():
+    """JAX's x64 switch is a thread-local context: the service's worker
+    thread enters it for the float64 entry points itself, and the caller's
+    thread is left as it was."""
+    import jax
+    service = make_service(window_s=0.01)
+    dispatch.clear_cache()
+    serve_all(service, [svc.CharacterizeRequest("A1", (1.1,)),
+                        svc.MinLatencyRequest("B2", (1.0,))])
+    for entry in ("characterize", "min_latency"):
+        exes = dispatch.executables(entry)
+        assert exes and all("f64" in c.as_text() for c in exes), entry
+    assert not jax.config.jax_enable_x64
+
+
 def test_fleet_coalescing_parity():
     service = make_service(window_s=0.05)
     names = service.workload_names
