@@ -290,9 +290,10 @@ def run_flat(entry: str, feats: dict, phases, coef_lo, coef_hi,
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
-    batched, replicated = flat_operands(feats, phases, coef_lo, coef_hi,
-                                        target_loss_pct, cand_v, lat_feat,
-                                        cand_t, cand_valid, model_coeffs)
+    with dispatch_lib.span(entry + ".lower"):
+        batched, replicated = flat_operands(
+            feats, phases, coef_lo, coef_hi, target_loss_pct, cand_v,
+            lat_feat, cand_t, cand_valid, model_coeffs)
     coef_lo, coef_hi, target, cand_v = replicated
     n_intervals = batched[9].shape[1]
 
@@ -326,6 +327,7 @@ def run_flat(entry: str, feats: dict, phases, coef_lo, coef_hi,
             for k, a in out.items()}
 
 
+@dispatch_lib.span("controller_scan")
 def run_batched(wb: WorkloadBatch, phases: np.ndarray, coef_lo, coef_hi,
                 target_loss_pct: float, cand_v: np.ndarray,
                 lat_feat: np.ndarray, cand_timings: np.ndarray,

@@ -44,6 +44,15 @@ Both dispatched paths are sliced back to the caller's N and are bit-exact
 per element against the direct (unbucketed) calls, which every entry point
 keeps as its parity reference (``dispatch="direct"``).
 
+Host stages are timed by :func:`span`: each entry point's call, its
+operand lowering, and here the host-to-device copies (``put``), the
+compile, the blocking execution (``dispatch``) and the device-to-host
+copies (``fetch``).  A span is a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` and a record in a bounded in-memory log
+(:func:`spans`), stamped with ``time.perf_counter_ns`` and added to its
+entry's :func:`stats` row, so a profiler trace and the counters tell the
+same story without the profiler being on.
+
 On a multi-device mesh both paths run the kernel under ``shard_map`` over
 ``"batch"``: every kernel is lane-local, so each device runs it on its own
 slice of lanes and no operand is gathered (GSPMD refuses to partition the
@@ -55,10 +64,16 @@ controller's direct call runs unsharded on the default device.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import dataclasses
+import functools
+import itertools
 import os
 import threading
 import time
+import typing
 import warnings
 
 import jax
@@ -91,6 +106,28 @@ _LOCK = threading.Lock()
 _EXECUTABLES: dict = {}
 _KEY_LOCKS: dict = {}
 _STATS: dict = {}
+
+SPAN_PREFIX = "repro."
+SPAN_LOG_SIZE = 65536
+
+
+class SpanRecord(typing.NamedTuple):
+    """One closed :func:`span`: ``name`` carries the ``repro.`` prefix of
+    its trace event; times are ``time.perf_counter_ns()`` stamps."""
+
+    id: int
+    parent_id: int | None
+    name: str
+    start_ns: int
+    end_ns: int
+    attrs: dict
+
+
+_SPANS: collections.deque = collections.deque(maxlen=SPAN_LOG_SIZE)
+_SPANS_DROPPED = 0
+_SPAN_IDS = itertools.count(1)
+_SPAN_PARENT: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_span_parent", default=None)
 
 
 # --------------------------------------------------------------------------
@@ -157,6 +194,14 @@ def stats(entry: str | None = None) -> dict:
     ``dispatch_us_total`` and ``dispatch_us_last`` (blocking wall time of
     the compiled executions, cumulative and most-recent — compile time is
     excluded, so reuse *and* steady latency are separately inspectable).
+    Every :func:`span` adds its wall time to ``<stage>_us_total`` and
+    ``<stage>_us_last`` of its entry's row (``span("fleet.lower")`` ->
+    ``stats("fleet")["lower_us_total"]``; the entry's own span is stage
+    ``call``): ``lower`` (operand building on the host), ``put`` (padding
+    and host-to-device copies), ``compile``, ``dispatch``, ``fetch``
+    (device-to-host copies and the slice to N), and on row ``tables`` one
+    stage per reliability policy.  :func:`spans` holds the spans
+    themselves.
     Entries whose callers pass ``config_label`` (the engine paths that
     resolve an ``autotune.KernelConfig`` per dispatch) additionally report
     ``config_last`` (the label of the most recent call) and
@@ -181,8 +226,65 @@ def record_gauge(entry: str, **gauges) -> None:
 
 
 def reset_stats() -> None:
+    """Clear every counter, gauge and the span log."""
+    global _SPANS_DROPPED
     with _LOCK:
         _STATS.clear()
+        _SPANS.clear()
+        _SPANS_DROPPED = 0
+
+
+@contextlib.contextmanager
+def span(name: str, **attrs):
+    """Time the ``with`` block as one host stage.
+
+    ``name`` is ``<entry>`` or ``<entry>.<stage>``: the block runs under
+    ``jax.profiler.TraceAnnotation("repro." + name, **attrs)``, its
+    ``perf_counter_ns`` duration is added to ``<stage>_us_total`` (stage
+    ``call`` for a bare entry) on the entry's :func:`stats` row, and a
+    :class:`SpanRecord` goes to the log that :func:`spans` returns.  The
+    parent is the span open in the caller's context (a
+    ``contextvars.ContextVar``), so nesting holds per thread and per
+    asyncio task; work handed to an executor keeps it only when run under
+    ``contextvars.copy_context()``."""
+    sid, parent, t0 = next(_SPAN_IDS), _SPAN_PARENT.get(), None
+    token = _SPAN_PARENT.set(sid)
+    try:
+        # the stamps sit just inside the annotation: logging waits until
+        # it has closed, so the trace event and the record differ by one
+        # clock offset
+        with jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **attrs):
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                t1 = time.perf_counter_ns()
+    finally:
+        _SPAN_PARENT.reset(token)
+        if t0 is not None:
+            _log(SpanRecord(sid, parent, SPAN_PREFIX + name, t0, t1, attrs))
+
+
+def _log(record: SpanRecord) -> None:
+    global _SPANS_DROPPED
+    entry, _, stage = record.name[len(SPAN_PREFIX):].partition(".")
+    key = (stage or "call") + "_us_"
+    us = (record.end_ns - record.start_ns) / 1e3
+    with _LOCK:
+        if len(_SPANS) == _SPANS.maxlen:
+            _SPANS_DROPPED += 1
+        _SPANS.append(record)
+        s = _stats_entry(entry)
+        s[key + "total"] = s.get(key + "total", 0.0) + us
+        s[key + "last"] = us
+
+
+def spans() -> tuple:
+    """``(records, dropped)``: the logged :class:`SpanRecord` s in the
+    order they closed (at most :data:`SPAN_LOG_SIZE`, the oldest dropped
+    first) and how many were dropped since :func:`reset_stats`."""
+    with _LOCK:
+        return list(_SPANS), _SPANS_DROPPED
 
 
 def executables(entry: str) -> list:
@@ -242,35 +344,36 @@ def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
             with _LOCK:
                 compiled = _EXECUTABLES.get(key)
             if compiled is None:
-                jitted = jax.jit(fn, donate_argnums=tuple(range(len(args)))
+                jitted = jax.jit(_named(fn),
+                                 donate_argnums=tuple(range(len(args)))
                                  if donate else ())
-                t0 = time.perf_counter()
-                with warnings.catch_warnings():
+                with span(entry + ".compile"), warnings.catch_warnings():
                     warnings.filterwarnings(
                         "ignore",
                         message="Some donated buffers were not usable")
                     compiled = jitted.lower(*args).compile()
-                us = (time.perf_counter() - t0) * 1e6
                 with _LOCK:
                     _EXECUTABLES[key] = compiled
-                    s = _stats_entry(entry)
-                    s["compiles"] += 1
-                    s["compile_us_total"] += us
+                    _stats_entry(entry)["compiles"] += 1
             else:
                 with _LOCK:
                     _stats_entry(entry)["hits"] += 1
     else:
         with _LOCK:
             _stats_entry(entry)["hits"] += 1
-    t0 = time.perf_counter()
-    out = compiled(*args)
-    out = jax.block_until_ready(out)
-    us = (time.perf_counter() - t0) * 1e6
-    with _LOCK:
-        s = _stats_entry(entry)
-        s["dispatch_us_total"] += us
-        s["dispatch_us_last"] = us
-    return out
+    with span(entry + ".dispatch"):
+        return jax.block_until_ready(compiled(*args))
+
+
+def _named(fn):
+    """``fn`` as jit will name its module: a ``functools.partial`` takes
+    the name of the function it wraps (``jit__controller_flat_fn``, not
+    ``jit__unknown``)."""
+    if not isinstance(fn, functools.partial):
+        return fn
+    named = functools.partial(fn)         # flattens nested partials
+    named.__name__ = named.func.__name__
+    return named
 
 
 # --------------------------------------------------------------------------
@@ -335,6 +438,11 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     are mesh-divisible by construction.
 
     ``mode``: "auto" (bucket, chunk on overflow), "bucketed", "chunked".
+    The host stages run under :func:`span`: ``<entry>.put`` (padding and
+    the copies to the device as far as the host waits for them), the
+    executable's ``compile`` / ``dispatch`` (:func:`aot_call`) and
+    ``<entry>.fetch`` (the copies back and the slice to N); ``put`` and
+    ``fetch`` carry the bytes copied as attr ``bytes``.
     ``config_label`` is forwarded to :func:`aot_call` for stats reporting
     of the caller's resolved kernel-tuning config (see that docstring).
     """
@@ -361,36 +469,39 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     bucket = pick_bucket(n, fits) if mode != "chunked" else None
 
     if bucket is not None:
-        resident = bucket
-        args = tuple(jnp.asarray(pad_axis(a, bucket)) for a in batched) \
-            + (jnp.asarray(_valid_mask(n, bucket)),)
-        if n_devices > 1:
-            args = tuple(
-                jax.device_put(a, mesh_lib.batch_sharding(mesh, a.ndim))
-                for a in args)
-        rep = _replicate(replicated, mesh, n_devices)
+        with span(entry + ".put",
+                  bytes=_put_bytes(batched, replicated, bucket)):
+            args = tuple(jnp.asarray(pad_axis(a, bucket)) for a in batched) \
+                + (jnp.asarray(_valid_mask(n, bucket)),)
+            if n_devices > 1:
+                args = tuple(
+                    jax.device_put(a, mesh_lib.batch_sharding(mesh, a.ndim))
+                    for a in args)
+            rep = _replicate(replicated, mesh, n_devices)
         if n_devices > 1:
             kernel = lane_sharded(kernel, mesh, len(batched), len(rep), 0)
         out = aot_call(entry, kernel, args[:-1] + rep + args[-1:],
-                       statics_key=statics_key, resident=resident,
+                       statics_key=statics_key, resident=bucket,
                        config_label=config_label)
-        out = {k: np.asarray(v)[:n] for k, v in out.items()}
-        return out
+        with span(entry + ".fetch", bytes=_out_bytes(out)):
+            return {k: np.asarray(v)[:n] for k, v in out.items()}
 
     # ---- chunked megabatch: lax.map over fixed-size chunks ---------------
     chunk = pick_bucket(n, fits) or fits[-1]
     k = -(-n // chunk)
-    stacked = tuple(
-        jnp.asarray(pad_axis(a, k * chunk).reshape((k, chunk)
-                                                   + a.shape[1:]))
-        for a in batched)
-    valid = jnp.asarray(_valid_mask(n, k * chunk).reshape(k, chunk))
-    if n_devices > 1:
-        put = lambda a: jax.device_put(
-            a, mesh_lib.chunked_batch_sharding(mesh, a.ndim))
-        stacked = tuple(put(a) for a in stacked)
-        valid = put(valid)
-    rep = _replicate(replicated, mesh, n_devices)
+    with span(entry + ".put",
+              bytes=_put_bytes(batched, replicated, k * chunk)):
+        stacked = tuple(
+            jnp.asarray(pad_axis(a, k * chunk).reshape((k, chunk)
+                                                       + a.shape[1:]))
+            for a in batched)
+        valid = jnp.asarray(_valid_mask(n, k * chunk).reshape(k, chunk))
+        if n_devices > 1:
+            put = lambda a: jax.device_put(
+                a, mesh_lib.chunked_batch_sharding(mesh, a.ndim))
+            stacked = tuple(put(a) for a in stacked)
+            valid = put(valid)
+        rep = _replicate(replicated, mesh, n_devices)
     with _LOCK:
         _stats_entry(entry)["chunked_calls"] += 1
     fn = _chunk_fn(kernel, len(stacked))
@@ -399,8 +510,21 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     out = aot_call(entry + "/chunked", fn,
                    stacked + (valid,) + rep, statics_key=statics_key,
                    donate=True, resident=chunk, config_label=config_label)
-    return {key: np.asarray(v).reshape((k * chunk,) + v.shape[2:])[:n]
-            for key, v in out.items()}
+    with span(entry + ".fetch", bytes=_out_bytes(out)):
+        return {key: np.asarray(v).reshape((k * chunk,) + v.shape[2:])[:n]
+                for key, v in out.items()}
+
+
+def _put_bytes(batched, replicated, lanes: int) -> int:
+    """Bytes a dispatch copies to the device: the batched operands and
+    the lane mask padded to ``lanes``, and the replicated operands."""
+    per_lane = 1 + sum(a.itemsize * int(np.prod(a.shape[1:]))
+                       for a in batched)
+    return lanes * per_lane + sum(np.asarray(a).nbytes for a in replicated)
+
+
+def _out_bytes(out: dict) -> int:
+    return sum(int(v.nbytes) for v in out.values())
 
 
 def _replicate(replicated, mesh, n_devices: int) -> tuple:

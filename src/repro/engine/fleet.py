@@ -48,6 +48,7 @@ import numpy as np
 from repro import power as power_lib
 from repro.dram import circuit, errors
 from repro.engine import controller
+from repro.engine import dispatch as dispatch_lib
 from repro.engine import solve as engine_solve
 from repro.engine import test1 as engine_test1
 from repro.engine.batch import WorkloadBatch
@@ -386,6 +387,7 @@ def ecc_policies(*, profile: str = "secded",
             HammerFloor(float(hammer_window_ms), hammer_scale))
 
 
+@dispatch_lib.span("tables")
 def build_tables(grid: DimmGrid, cand_v, *, step: float = 2.5,
                  max_latency: float = 20.0, temp_c: float = 20.0,
                  mesh=None, dispatch: str = "auto",
@@ -431,7 +433,8 @@ def build_tables(grid: DimmGrid, cand_v, *, step: float = 2.5,
                         float(temp_c), mesh, dispatch)
     state = PolicyState()
     for policy in policies:
-        state = policy.apply(ctx, state)
+        with dispatch_lib.span("tables." + type(policy).__name__):
+            state = policy.apply(ctx, state)
     valid = state.valid
     if not valid[:, -1].all():
         bad = [m for m, ok in zip(grid.modules, valid[:, -1]) if not ok]
@@ -585,6 +588,7 @@ class FleetBatchResult:
         return out
 
 
+@dispatch_lib.span("fleet")
 def run_fleet_batched(wb: WorkloadBatch, tables: FleetTables,
                       phases: np.ndarray, coef_lo, coef_hi,
                       target_loss_pct: float, *, impl: str = "auto",
@@ -610,29 +614,31 @@ def run_fleet_batched(wb: WorkloadBatch, tables: FleetTables,
     per-lane parity reference).
     """
     w, d = wb.n_workloads, tables.n_dimms
-    feats = {key: np.asarray(a)
-             for key, a in engine_solve._wb_feats(wb).items()}
-    rep_w = lambda a: np.repeat(a, d, axis=0)          # [W,...] -> [W*D,...]
-    tile_d = lambda a: np.tile(a, (w,) + (1,) * (a.ndim - 1))
-    flat_feats = {key: rep_w(a) for key, a in feats.items()}
-    phases = np.asarray(phases)
-    if phases.shape[1] == w * d:                       # per-lane columns
-        phases_flat = phases
-    elif phases.shape[1] == w:                         # per-workload columns
-        phases_flat = np.repeat(phases, d, axis=1)     # [T, W*D]
-    else:
-        raise ValueError(f"phases must be [T, {w}] (per workload) or "
-                         f"[T, {w * d}] (per lane); got {phases.shape}")
-    cand_t = {"t_rcd": tile_d(tables.timings[:, :, 0]),
-              "t_rp": tile_d(tables.timings[:, :, 1]),
-              "t_ras": tile_d(tables.timings[:, :, 2])}
-    # heterogeneous power models: one eager [D, NCOEFF] gather, tiled per
-    # workload — the coefficients are just more per-lane columns in jit.
-    coeff_lanes = tile_d(power_lib.coeff_rows(tables.device_models,
-                                              np.float32))
+    with dispatch_lib.span("fleet.lower"):
+        feats = {key: np.asarray(a)
+                 for key, a in engine_solve._wb_feats(wb).items()}
+        rep_w = lambda a: np.repeat(a, d, axis=0)      # [W,...] -> [W*D,...]
+        tile_d = lambda a: np.tile(a, (w,) + (1,) * (a.ndim - 1))
+        flat_feats = {key: rep_w(a) for key, a in feats.items()}
+        phases = np.asarray(phases)
+        if phases.shape[1] == w * d:                   # per-lane columns
+            phases_flat = phases
+        elif phases.shape[1] == w:                     # per-workload columns
+            phases_flat = np.repeat(phases, d, axis=1)     # [T, W*D]
+        else:
+            raise ValueError(f"phases must be [T, {w}] (per workload) or "
+                             f"[T, {w * d}] (per lane); got {phases.shape}")
+        cand_t = {"t_rcd": tile_d(tables.timings[:, :, 0]),
+                  "t_rp": tile_d(tables.timings[:, :, 1]),
+                  "t_ras": tile_d(tables.timings[:, :, 2])}
+        # heterogeneous power models: one eager [D, NCOEFF] gather, tiled
+        # per workload — the coefficients are more per-lane columns in jit.
+        coeff_lanes = tile_d(power_lib.coeff_rows(tables.device_models,
+                                                  np.float32))
+        lat_feat, cand_valid = tile_d(tables.lat_feat), tile_d(tables.valid)
     out = controller.run_flat(
         "fleet", flat_feats, phases_flat, coef_lo, coef_hi, target_loss_pct,
-        tables.cand_v, tile_d(tables.lat_feat), cand_t, tile_d(tables.valid),
+        tables.cand_v, lat_feat, cand_t, cand_valid,
         model_coeffs=coeff_lanes, impl=impl, dispatch=dispatch, mesh=mesh,
         max_elements_resident=max_elements_resident)
     selected = np.asarray(tables.cand_v, np.float64)[out["selected_idx"]]

@@ -315,6 +315,7 @@ def _pad_flat(arrays: list, n_devices: int) -> tuple:
             for a in arrays], pad
 
 
+@dispatch_lib.span("characterize.lower")
 def characterize_inputs(grid: DimmGrid, v, t_grid, patterns, retention_ms,
                         t_rcd: float, t_rp: float) -> tuple:
     """Eager per-lane operands of ``_characterize_flat_fn`` for the
@@ -451,6 +452,7 @@ def _beat_error_flat_fn(x_rcd, x_rp, floor, vmin, v, field_n, valid):
 _beat_error_flat = jax.jit(_beat_error_flat_fn)
 
 
+@dispatch_lib.span("beat_error.lower")
 def beat_error_inputs(grid: DimmGrid, v, t_rcd, t_rp, t_grid) -> list:
     """Eager per-lane operands of ``_beat_error_flat_fn`` for the flattened
     D x K x T grid.
@@ -481,6 +483,7 @@ def beat_error_inputs(grid: DimmGrid, v, t_rcd, t_rp, t_grid) -> list:
     ]
 
 
+@dispatch_lib.span("beat_error")
 def beat_error_batch(grid: DimmGrid, v, t_rcd=10.0, t_rp=10.0,
                      t_grid=(20.0,), *, mesh=None, impl: str = "auto",
                      dispatch: str = "auto") -> dict:
@@ -587,6 +590,7 @@ def _characterize_scalar(grid, v, t_grid, patterns, retention_ms,
         tmin["rcd"], tmin["rp"], row_map, line_map, weak)
 
 
+@dispatch_lib.span("characterize")
 def characterize_batch(grid: DimmGrid, v_grid, t_grid=(20.0,),
                        patterns=("0xaa",),
                        retention_ms=RETENTION_GRID_MS,

@@ -73,6 +73,7 @@ thread-local, so callers on other threads keep their own setting.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import dataclasses
 import functools
 from concurrent.futures import ThreadPoolExecutor
@@ -497,8 +498,10 @@ class EngineService:
             self._stats["max_flush_lanes"], batched[0].shape[0])
         loop = asyncio.get_running_loop()
         try:
+            # in this task's context, so the dispatch spans keep their parent
             out = await loop.run_in_executor(
-                self._executor, self._run_dispatch, g.spec, batched, "auto")
+                self._executor, contextvars.copy_context().run,
+                self._run_dispatch, g.spec, batched, "auto")
         except Exception as e:          # noqa: BLE001 — fail every lane
             for low, fut, cost in live:
                 self._finish(fut, cost, error=e)

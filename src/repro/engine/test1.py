@@ -264,20 +264,22 @@ def _run_batched(grid, v, pattern_groups, rounds, t_rcd, t_rp, banks, rows,
     d_, v_, p_ = grid.n_dimms, v.size, len(pattern_groups)
     shape4 = (d_, v_, p_, rounds)
 
-    p_word = _word_probs(grid, v, t_rcd, t_rp, temp_c, rows)
-    kd = _bank_key_data([d.index for d in grid.dimms], rounds, seed, banks)
-    patterns = np.array([[scalar_test1.DATA_PATTERNS[a],
-                          scalar_test1.DATA_PATTERNS[b]]
-                         for a, b in pattern_groups], np.uint32)
+    with dispatch_lib.span("test1.lower"):
+        p_word = _word_probs(grid, v, t_rcd, t_rp, temp_c, rows)
+        kd = _bank_key_data([d.index for d in grid.dimms], rounds, seed,
+                            banks)
+        patterns = np.array([[scalar_test1.DATA_PATTERNS[a],
+                              scalar_test1.DATA_PATTERNS[b]]
+                             for a, b in pattern_groups], np.uint32)
 
-    # flatten D x V x P x R into the leading batch axis
-    flat = lambda a, trail: np.ascontiguousarray(
-        np.broadcast_to(a, shape4 + trail).reshape((-1,) + trail))
-    inputs = [
-        flat(p_word[:, :, None, None], (banks, rows)),
-        flat(kd[:, None, None], (banks, 2, 2)),
-        flat(np.arange(p_, dtype=np.int32)[None, None, :, None], ()),
-    ]
+        # flatten D x V x P x R into the leading batch axis
+        flat = lambda a, trail: np.ascontiguousarray(
+            np.broadcast_to(a, shape4 + trail).reshape((-1,) + trail))
+        inputs = [
+            flat(p_word[:, :, None, None], (banks, rows)),
+            flat(kd[:, None, None], (banks, 2, 2)),
+            flat(np.arange(p_, dtype=np.int32)[None, None, :, None], ()),
+        ]
 
     statics = dict(banks=banks, rows=rows, words=words, nplanes=nplanes,
                    inject_impl=inject_impl)
@@ -323,6 +325,7 @@ def _run_scalar(grid, v, pattern_groups, rounds, t_rcd, t_rp, banks, rows,
         err_rows, res.total_bits, res.total_lines)
 
 
+@dispatch_lib.span("test1")
 def run_batch(grid: DimmGrid, v_grid,
               pattern_groups=tuple(scalar_test1.PATTERN_GROUPS), *,
               rounds: int = 1, t_rcd: float = 10.0, t_rp: float = 10.0,
@@ -458,6 +461,7 @@ def _run_hammer_scalar(grid, v, h, rounds, pattern_group, banks, rows,
         res.total_lines)
 
 
+@dispatch_lib.span("hammer")
 def run_hammer_batch(grid: DimmGrid, v_grid, hammer_counts, *,
                      rounds: int = 1, pattern_group=("0xaa", "0x55"),
                      banks: int = 8, rows: int = 64, row_bytes: int = 4096,
@@ -503,19 +507,21 @@ def run_hammer_batch(grid: DimmGrid, v_grid, hammer_counts, *,
 
     words = row_bytes // 4
     shape4 = (grid.n_dimms, v.size, h.size, rounds)
-    p_word = _hammer_word_probs(grid, v, h, rows)        # [D, V, H, B, rows]
-    kd = _bank_key_data([d.index for d in grid.dimms], rounds, seed, banks)
-    patterns = np.array([[scalar_test1.DATA_PATTERNS[pattern_group[0]],
-                          scalar_test1.DATA_PATTERNS[pattern_group[1]]]],
-                        np.uint32)                       # [1, 2]
+    with dispatch_lib.span("hammer.lower"):
+        p_word = _hammer_word_probs(grid, v, h, rows)    # [D, V, H, B, rows]
+        kd = _bank_key_data([d.index for d in grid.dimms], rounds, seed,
+                            banks)
+        patterns = np.array([[scalar_test1.DATA_PATTERNS[pattern_group[0]],
+                              scalar_test1.DATA_PATTERNS[pattern_group[1]]]],
+                            np.uint32)                   # [1, 2]
 
-    flat = lambda a, trail: np.ascontiguousarray(
-        np.broadcast_to(a, shape4 + trail).reshape((-1,) + trail))
-    inputs = [
-        flat(p_word[:, :, :, None], (banks, rows)),
-        flat(kd[:, None, None], (banks, 2, 2)),
-        flat(np.zeros((1, 1, 1, 1), np.int32), ()),
-    ]
+        flat = lambda a, trail: np.ascontiguousarray(
+            np.broadcast_to(a, shape4 + trail).reshape((-1,) + trail))
+        inputs = [
+            flat(p_word[:, :, :, None], (banks, rows)),
+            flat(kd[:, None, None], (banks, 2, 2)),
+            flat(np.zeros((1, 1, 1, 1), np.int32), ()),
+        ]
     statics = dict(banks=banks, rows=rows, words=words, nplanes=nplanes,
                    inject_impl=inject_impl)
     out = _dispatch_test1_plane("hammer", inputs, patterns, statics, mesh,
@@ -564,6 +570,7 @@ def _min_latency_flat_fn(x_rcd, x_rp, field_max, v, recovery_floor,
 _min_latency_flat = jax.jit(_min_latency_flat_fn)
 
 
+@dispatch_lib.span("min_latency.lower")
 def min_latency_inputs(grid: DimmGrid, v_grid, *, step: float = 2.5,
                        max_latency: float = 20.0,
                        temp_c: float = 20.0) -> tuple:
@@ -603,6 +610,7 @@ def min_latency_inputs(grid: DimmGrid, v_grid, *, step: float = 2.5,
     return inputs, lat
 
 
+@dispatch_lib.span("min_latency")
 def find_min_latency_batch(grid: DimmGrid, v_grid, *, step: float = 2.5,
                            max_latency: float = 20.0, temp_c: float = 20.0,
                            mesh=None, impl: str = "auto",
