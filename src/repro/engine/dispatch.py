@@ -42,7 +42,10 @@ rather than throughput.  This module gives every entry point
 
 Both dispatched paths are sliced back to the caller's N and are bit-exact
 per element against the direct (unbucketed) calls, which every entry point
-keeps as its parity reference (``dispatch="direct"``).
+keeps as its parity reference (``dispatch="direct"``).  Their float64
+outputs leave the executable as raw ``uint32`` words, which the host views
+as float64 again: a TPU emulates float64, and converting it to IEEE bits
+on the way to the host copies at a tenth of the float32 rate.
 
 Host stages are timed by :func:`span`: each entry point's call, its
 operand lowering, and here the host-to-device copies (``put``), the
@@ -200,8 +203,9 @@ def stats(entry: str | None = None) -> dict:
     ``call``): ``lower`` (operand building on the host), ``put`` (padding
     and host-to-device copies), ``compile``, ``dispatch``, ``fetch``
     (device-to-host copies and the slice to N), and on row ``tables`` one
-    stage per reliability policy.  :func:`spans` holds the spans
-    themselves.
+    stage per reliability policy.  ``wire_bytes_total`` counts the output
+    bytes fetched as float64 words (:func:`dispatch_flat`).  :func:`spans`
+    holds the spans themselves.
     Entries whose callers pass ``config_label`` (the engine paths that
     resolve an ``autotune.KernelConfig`` per dispatch) additionally report
     ``config_last`` (the label of the most recent call) and
@@ -442,7 +446,9 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     the copies to the device as far as the host waits for them), the
     executable's ``compile`` / ``dispatch`` (:func:`aot_call`) and
     ``<entry>.fetch`` (the copies back and the slice to N); ``put`` and
-    ``fetch`` carry the bytes copied as attr ``bytes``.
+    ``fetch`` carry the bytes copied as attr ``bytes``.  Float64 outputs
+    cross as ``uint32`` words converted inside the executable; ``fetch``
+    carries their bytes as attr ``wire_bytes``.
     ``config_label`` is forwarded to :func:`aot_call` for stats reporting
     of the caller's resolved kernel-tuning config (see that docstring).
     """
@@ -468,6 +474,7 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
                 "max_bucket")
     bucket = pick_bucket(n, fits) if mode != "chunked" else None
 
+    kernel = _f64_as_words(kernel)
     if bucket is not None:
         with span(entry + ".put",
                   bytes=_put_bytes(batched, replicated, bucket)):
@@ -483,8 +490,8 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
         out = aot_call(entry, kernel, args[:-1] + rep + args[-1:],
                        statics_key=statics_key, resident=bucket,
                        config_label=config_label)
-        with span(entry + ".fetch", bytes=_out_bytes(out)):
-            return {k: np.asarray(v)[:n] for k, v in out.items()}
+        with _fetch_span(entry, out):
+            return {k: v[:n] for k, v in _host_outputs(out).items()}
 
     # ---- chunked megabatch: lax.map over fixed-size chunks ---------------
     chunk = pick_bucket(n, fits) or fits[-1]
@@ -510,9 +517,9 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     out = aot_call(entry + "/chunked", fn,
                    stacked + (valid,) + rep, statics_key=statics_key,
                    donate=True, resident=chunk, config_label=config_label)
-    with span(entry + ".fetch", bytes=_out_bytes(out)):
-        return {key: np.asarray(v).reshape((k * chunk,) + v.shape[2:])[:n]
-                for key, v in out.items()}
+    with _fetch_span(entry, out):
+        return {key: v.reshape((k * chunk,) + v.shape[2:])[:n]
+                for key, v in _host_outputs(out).items()}
 
 
 def _put_bytes(batched, replicated, lanes: int) -> int:
@@ -523,8 +530,121 @@ def _put_bytes(batched, replicated, lanes: int) -> int:
     return lanes * per_lane + sum(np.asarray(a).nbytes for a in replicated)
 
 
-def _out_bytes(out: dict) -> int:
-    return sum(int(v.nbytes) for v in out.values())
+def _f64_as_words(kernel):
+    """``kernel`` returning ``(same, rows, lanes)``: its float64 outputs as
+    :func:`_f64_words`, the others untouched in ``same``.  A chip that
+    emulates float64 turns it into IEEE bits inside the executable, where
+    it is cheap, and not in the copy to the host, where it is not.  In
+    ``rows`` an ``[..., F]`` output's words fill a last axis of ``2F``,
+    which a TPU lays out row-major, so the host views the copy without
+    another; ``lanes`` holds the ``[N]`` outputs as ``[N, 2]``.  Named
+    after ``kernel`` and given its signature, so its module keeps its name
+    and parameter names, and a kernel with no float64 output traces the
+    program it traced before."""
+    def fn(*args):
+        same, rows, lanes = {}, {}, {}
+        for k, v in kernel(*args).items():
+            if v.dtype != jnp.float64:
+                same[k] = v
+            elif v.ndim == 1:
+                lanes[k] = _f64_words(v)
+            else:
+                rows[k] = _f64_words(v).reshape(v.shape[:-1] + (-1,))
+        return same, rows, lanes
+    fn.__name__ = getattr(_named(kernel), "__name__", fn.__name__)
+    fn.__wrapped__ = kernel
+    return fn
+
+
+_EXP_STEPS = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+
+
+def _f64_words(x):
+    """The IEEE-754 bits of float64 ``x`` as ``uint32[..., 2]``, low word
+    first (a little-endian float64), so the host reads them back with
+    ``.view(np.float64)``: a ``bitcast_convert_type`` where float64 is
+    native, and :func:`_emulated_f64_words` on a TPU, whose XLA cannot
+    bitcast the float64 it emulates."""
+    return jax.lax.platform_dependent(
+        x, tpu=_emulated_f64_words,
+        default=lambda v: jax.lax.bitcast_convert_type(v, jnp.uint32))
+
+
+def _emulated_f64_words(x):
+    """:func:`_f64_words` by float64 arithmetic that IEEE float64 does
+    exactly: scaling by powers of two, a subtraction of 1 from a value in
+    [1, 2), ``floor`` and integer-valued converts.  A TPU's emulated
+    float64 drops low bits in such arithmetic near the bottom of the
+    float32 range: on a v5e the words equal the runtime's own copy for
+    magnitudes above about 2**-86, and may lose low bits below.
+
+    The exponent is found by ten halving steps; the 52-bit fraction is
+    rounded to nearest even, which is a no-op for a true float64 and
+    rounds an emulated value with more than 53 significant bits as the
+    copy to the host would.  Infinities keep their bits; a NaN comes back as the quiet NaN of its
+    sign; a zero as +0.0, which is what the copy makes of an emulated
+    zero unless both of its float32 halves are -0.0 (only the high half's
+    sign can be seen).  Subnormals are exact where the arithmetic keeps
+    them (XLA:CPU flushes them to zero)."""
+    a = jnp.abs(x)
+    finite = jnp.isfinite(x)
+    m = jnp.where(finite & (a > 0), a, 1.0)
+    e = jnp.zeros(x.shape, jnp.int32)
+    for k in _EXP_STEPS:                      # m = a * 2**-e in [1, 2)
+        big = m >= 2.0 ** k
+        m = jnp.where(big, m * 2.0 ** -k, m)
+        e = e + jnp.where(big, k, 0)
+    for k in _EXP_STEPS:
+        small = m < 2.0 ** (1 - k)
+        m = jnp.where(small, m * 2.0 ** k, m)
+        e = e - jnp.where(small, k, 0)
+    sub = e < -1022                           # a < 2**-1022: exponent 0
+    frac = jnp.round(jnp.where(sub, a * 2.0 ** 1022 * 2.0 ** 52,
+                               (m - 1.0) * 2.0 ** 52))
+    carry = frac >= 2.0 ** 52                 # rounded up to the next power
+    frac = jnp.where(carry, 0.0, frac)
+    biased = jnp.where(sub, 0, e + 1023) + carry
+    zero = a == 0
+    biased = jnp.where(finite, jnp.where(zero, 0, biased), 2047)
+    frac = jnp.where(finite & ~zero, frac,
+                     jnp.where(jnp.isnan(x), 2.0 ** 51, 0.0))
+    # the fraction as 20 + 16 + 16 bits: each piece converts exactly
+    top20 = jnp.floor(frac * 2.0 ** -32)
+    low32 = frac - top20 * 2.0 ** 32
+    mid16 = jnp.floor(low32 * 2.0 ** -16)
+    u = lambda v: v.astype(jnp.uint32)
+    sign = jax.lax.bitcast_convert_type(x.astype(jnp.float32),
+                                        jnp.uint32) & jnp.uint32(1 << 31)
+    sign = jnp.where(zero, jnp.uint32(0), sign)
+    hi = sign | (u(biased) << 20) | u(top20)
+    lo = (u(mid16) << 16) | u(low32 - mid16 * 2.0 ** 16)
+    return jnp.stack([lo, hi], axis=-1)
+
+
+def _fetch_span(entry: str, out: tuple):
+    """The ``<entry>.fetch`` span: attr ``bytes`` is every output byte
+    copied back, ``wire_bytes`` the float64 ones that crossed as words
+    (also added to the entry's ``wire_bytes_total``)."""
+    same, rows, lanes = out
+    wire = sum(int(v.nbytes) for v in (*rows.values(), *lanes.values()))
+    with _LOCK:
+        s = _stats_entry(entry)
+        s["wire_bytes_total"] = s.get("wire_bytes_total", 0) + wire
+    return span(entry + ".fetch", wire_bytes=wire,
+                bytes=wire + sum(int(v.nbytes) for v in same.values()))
+
+
+def _host_outputs(out: tuple) -> dict:
+    """The executable's ``(same, rows, lanes)`` on the host, as one dict
+    in key order, each run of ``uint32`` words viewed as the float64s it
+    holds.  A narrow last axis arrives column-major from a TPU and is put
+    in row order first (a copy of a small output)."""
+    same, rows, lanes = out
+    as_f64 = lambda w: np.ascontiguousarray(w).view(np.float64)
+    host = {k: np.asarray(v) for k, v in same.items()}
+    host.update((k, as_f64(v)) for k, v in rows.items())
+    host.update((k, as_f64(v)[..., 0]) for k, v in lanes.items())
+    return {k: host[k] for k in sorted(host)}
 
 
 def _replicate(replicated, mesh, n_devices: int) -> tuple:

@@ -12,6 +12,8 @@ import subprocess
 import sys
 import textwrap
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
@@ -186,6 +188,78 @@ class TestChunked:
         assert dispatch.stats("test1")["chunked_calls"] == 1
         for f in T1_QUANTITIES:
             np.testing.assert_array_equal(getattr(got, f), getattr(ref, f))
+
+
+def _special_f64(n_lanes: int) -> np.ndarray:
+    """[n_lanes, 8] float64 with NaN, +-inf, +-0.0, subnormals, the
+    neighbours of 1.0, the extremes and random bit patterns."""
+    f = np.finfo(np.float64)
+    special = np.array([np.nan, np.inf, -np.inf, 0.0, -0.0,
+                        f.smallest_subnormal, -3 * f.smallest_subnormal,
+                        f.tiny - f.smallest_subnormal, f.tiny, 1.0,
+                        np.nextafter(1.0, 2.0), np.nextafter(1.0, 0.0),
+                        -np.nextafter(1.0, 2.0), f.max, -f.max, 1 / 3])
+    bits = np.random.default_rng(5).integers(0, 2**64, n_lanes * 8,
+                                             dtype=np.uint64)
+    out = bits.view(np.float64).copy()
+    out[:special.size] = special
+    return out.reshape(n_lanes, 8)
+
+
+def _typed_kernel(x, k, valid):
+    """Outputs of every dtype the dispatch layer carries; dead lanes
+    zeroed as the engine kernels do."""
+    live = valid[:, None]
+    return {"f64": jnp.where(live, x, 0.0), "f64_sum": x[:, 0] + x[:, 1],
+            "f32": jnp.where(live, x.astype(jnp.float32), 0.0),
+            "i32": k * 3, "b": valid & (k[:, 0] > 0)}
+
+
+@pytest.mark.parametrize("mode,n", [("bucketed", 13), ("bucketed", 16),
+                                    ("chunked", 6), ("chunked", 13)])
+def test_float64_outputs_cross_as_words_bit_exact(mode, n):
+    """Float64 outputs come back bit for bit as the direct call returns
+    them (dead lanes in the bucket or the last chunk); other dtypes keep
+    dtype and values; ``wire_bytes_total`` counts the float64 bytes."""
+    x = _special_f64(n)
+    k = np.arange(n * 2, dtype=np.int32).reshape(n, 2) - 5
+    name = f"words-{mode}-{n}"
+    dispatch.reset_stats()
+    with jax.enable_x64(True):
+        ref = jax.jit(_typed_kernel)(x, k, np.ones(n, bool))
+        got = dispatch.dispatch_flat(
+            name, _typed_kernel, [x, k], mode=mode,
+            config=dispatch.DispatchConfig(max_elements_resident=4))
+    assert sorted(got) == sorted(ref)
+    for key, want in ref.items():
+        want = np.asarray(want)
+        assert got[key].dtype == want.dtype and got[key].shape == want.shape
+        if want.dtype == np.float64:
+            np.testing.assert_array_equal(got[key].view(np.uint64),
+                                          want.view(np.uint64), err_msg=key)
+        else:
+            np.testing.assert_array_equal(got[key], want, err_msg=key)
+    lanes = 8 if n <= 8 else 16     # the bucket, or chunks of 4 lanes
+    assert dispatch.stats(name)["wire_bytes_total"] == lanes * (8 + 1) * 8
+
+
+def test_emulated_float64_words_exact_off_subnormals():
+    """The arithmetic a TPU runs in place of the bitcast gives the IEEE
+    bits of every normal value and infinity (subnormals are flushed by
+    XLA:CPU, so this host cannot check them); NaN stays NaN, and a zero
+    comes back as +0.0, as the chip's own copy returns most -0.0s."""
+    x = _special_f64(4096).ravel()
+    x = x[(np.abs(x) >= np.finfo(np.float64).tiny) | ~np.isfinite(x)
+          | (x == 0)]
+    with jax.enable_x64(True):
+        words = np.asarray(jax.jit(dispatch._emulated_f64_words)(x))
+    assert words.dtype == np.uint32 and words.shape == x.shape + (2,)
+    got = words.view(np.float64)[..., 0]
+    nan = np.isnan(x)
+    assert np.isnan(got[nan]).all()
+    want = np.where(x == 0, 0.0, x)                 # -0.0 -> +0.0
+    np.testing.assert_array_equal(got[~nan].view(np.uint64),
+                                  want[~nan].view(np.uint64))
 
 
 class TestSystemSweepParity:

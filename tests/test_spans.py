@@ -95,6 +95,23 @@ def test_test1_spans_nest_under_the_entry(grid):
     _stage_totals_match_log(records)
 
 
+@pytest.mark.parametrize("entry", ["characterize", "test1"])
+def test_fetch_counts_the_float64_words(grid, entry):
+    """Every float64 output crosses as words: all of characterize's
+    fetched bytes, none of Test 1's (integer counts)."""
+    dispatch.reset_stats()
+    if entry == "characterize":
+        _characterize(grid)
+    else:
+        engine_test1.run_batch(grid, [1.0, 1.2], [("0xaa", "0x55")], rows=8,
+                               row_bytes=256, inject_impl="reference")
+    (fetch,) = _by_name(dispatch.spans()[0])[f"repro.{entry}.fetch"]
+    assert fetch.attrs["bytes"] > 0
+    want = fetch.attrs["bytes"] if entry == "characterize" else 0
+    assert fetch.attrs["wire_bytes"] == want
+    assert dispatch.stats(entry)["wire_bytes_total"] == want
+
+
 def test_table_policies_nest_under_tables(grid):
     dispatch.reset_stats()
     fleet.build_tables(grid, np.array(CAND_V), policies=fleet.ecc_policies())

@@ -97,21 +97,48 @@ def test_sweep_solve_compiles_at_the_fleet_bucket(one_chip, no_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _characterize_specs(b, sharding):
+    """The characterization kernel's operands at ``b`` lanes (under x64)."""
+    lane = _sds((b,), jnp.float64, sharding)
+    return ([lane] * 8
+            + [_sds((b, population.FIELD_SIZE), jnp.float64, sharding),
+               _sds((len(chip_smoke.CHAR_PATTERNS),), jnp.float64, sharding),
+               _sds((len(population.RETENTION_GRID_MS),), jnp.float64,
+                    sharding),
+               _sds((b,), jnp.bool_, sharding)])
+
+
 def test_characterize_float64_compiles_at_4096_lanes(one_chip, no_cache):
     b = CHAR_BUCKET
     with jax.enable_x64(True):
-        lane = _sds((b,), jnp.float64, one_chip)
-        args = ([lane] * 8
-                + [_sds((b, population.FIELD_SIZE), jnp.float64, one_chip),
-                   _sds((len(chip_smoke.CHAR_PATTERNS),), jnp.float64,
-                        one_chip),
-                   _sds((len(population.RETENTION_GRID_MS),), jnp.float64,
-                        one_chip),
-                   _sds((b,), jnp.bool_, one_chip)])
         compiled = jax.jit(population._characterize_flat_fn).lower(
-            *args).compile()
+            *_characterize_specs(b, one_chip)).compile()
     out = compiled.memory_analysis().output_size_in_bytes
     assert out >= 2 * b * population.FIELD_SIZE * 8     # two f64 maps
+
+
+def test_characterize_fetches_float64_as_words_at_4096_lanes(one_chip,
+                                                             no_cache):
+    """The kernel as the dispatch layer runs it: every float64 output
+    bitcast to uint32 words inside the executable, which XLA:TPU must
+    accept; the maps keep their size on the wire."""
+    b = CHAR_BUCKET
+    fn = dispatch._f64_as_words(population._characterize_flat_fn)
+    with jax.enable_x64(True):
+        compiled = jax.jit(fn).lower(*_characterize_specs(b, one_chip)
+                                     ).compile()
+    assert compiled.as_text().startswith("HloModule jit__characterize_flat_fn")
+    same, rows, lanes = compiled.out_info
+    assert not same                 # every characterization output is f64
+    assert set(rows) == {"ber", "row_map", "line_map", "weak"}
+    assert set(lanes) == {"frac", "tmin_rcd", "tmin_rp"}
+    maps = [rows[k] for k in ("row_map", "line_map")]
+    assert all(m.dtype == jnp.uint32
+               and m.shape == (b, 2 * population.FIELD_SIZE) for m in maps)
+    wire = sum(int(np.prod(m.shape)) * m.dtype.itemsize for m in maps)
+    assert wire == 2 * b * population.FIELD_SIZE * 8    # two f64 maps
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out >= wire
 
 
 def _stress_operands():
