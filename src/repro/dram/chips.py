@@ -309,6 +309,23 @@ def population() -> tuple:
                  for i, (m, v, d, die, vmin) in enumerate(TABLE7))
 
 
+def resampled_population(n: int, seed: int) -> tuple:
+    """An operator's fleet of ``n`` DIMMs resampled from Table 7.
+
+    DIMM ``i`` copies a Table 7 row (vendor, date, die, V_min) drawn
+    uniformly with replacement by ``np.random.default_rng(seed)
+    .integers(len(TABLE7), size=n)``, so the vendor mix is 10:12:9 in
+    expectation.  Its ``index`` is ``len(TABLE7) + i``: distinct from the
+    Table 7 DIMMs (0-30) and from every other DIMM of the fleet, it gives
+    the DIMM its own susceptibility field and latency scale.  Its name is
+    the row's module and its position, ``B7.r0413``."""
+    rows = np.random.default_rng(seed).integers(len(TABLE7), size=int(n))
+    width = max(4, len(str(int(n) - 1)))
+    return tuple(DIMM(f"{TABLE7[r][0]}.r{i:0{width}d}", *TABLE7[r][1:],
+                      len(TABLE7) + i)
+                 for i, r in enumerate(rows))
+
+
 def by_vendor(vendor: str) -> list:
     return [d for d in population() if d.vendor == vendor]
 

@@ -183,6 +183,8 @@ def _leaf_key(x):
 def _stats_entry(entry: str) -> dict:
     return _STATS.setdefault(entry, {"calls": 0, "compiles": 0, "hits": 0,
                                      "chunked_calls": 0, "max_resident": 0,
+                                     "lanes_total": 0,
+                                     "padded_lanes_total": 0, "devices": 0,
                                      "compile_us_total": 0.0,
                                      "dispatch_us_total": 0.0,
                                      "dispatch_us_last": 0.0})
@@ -193,6 +195,9 @@ def stats(entry: str | None = None) -> dict:
     ``lower().compile()`` invocations = traces) / ``hits`` (warm-executable
     reuses) / ``chunked_calls`` / ``max_resident`` (largest resident flat
     batch actually materialized — the peak-memory proxy) /
+    ``lanes_total`` (true lanes dispatched) / ``padded_lanes_total``
+    (dead lanes added to fill the bucket or the last chunk) / ``devices``
+    (the mesh size of the latest dispatch, a gauge) /
     ``compile_us_total`` (wall time of the ``lower().compile()`` calls) /
     ``dispatch_us_total`` and ``dispatch_us_last`` (blocking wall time of
     the compiled executions, cumulative and most-recent — compile time is
@@ -446,9 +451,13 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     the copies to the device as far as the host waits for them), the
     executable's ``compile`` / ``dispatch`` (:func:`aot_call`) and
     ``<entry>.fetch`` (the copies back and the slice to N); ``put`` and
-    ``fetch`` carry the bytes copied as attr ``bytes``.  Float64 outputs
-    cross as ``uint32`` words converted inside the executable; ``fetch``
-    carries their bytes as attr ``wire_bytes``.
+    ``fetch`` carry the bytes copied as attr ``bytes``.  ``put`` also
+    carries the dispatch's shape, which the entry's :func:`stats` row
+    sums: ``lanes`` (N), ``padded_lanes`` (the bucket, or ``chunks x
+    chunk``, less N), ``devices`` (the mesh size) and ``chunks`` (1 for a
+    resident bucket).  Float64 outputs cross as ``uint32`` words
+    converted inside the executable; ``fetch`` carries their bytes as
+    attr ``wire_bytes``.
     ``config_label`` is forwarded to :func:`aot_call` for stats reporting
     of the caller's resolved kernel-tuning config (see that docstring).
     """
@@ -477,7 +486,8 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     kernel = _f64_as_words(kernel)
     if bucket is not None:
         with span(entry + ".put",
-                  bytes=_put_bytes(batched, replicated, bucket)):
+                  bytes=_put_bytes(batched, replicated, bucket),
+                  **_count_lanes(entry, n, bucket, n_devices, 1)):
             args = tuple(jnp.asarray(pad_axis(a, bucket)) for a in batched) \
                 + (jnp.asarray(_valid_mask(n, bucket)),)
             if n_devices > 1:
@@ -497,7 +507,8 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     chunk = pick_bucket(n, fits) or fits[-1]
     k = -(-n // chunk)
     with span(entry + ".put",
-              bytes=_put_bytes(batched, replicated, k * chunk)):
+              bytes=_put_bytes(batched, replicated, k * chunk),
+              **_count_lanes(entry, n, k * chunk, n_devices, k)):
         stacked = tuple(
             jnp.asarray(pad_axis(a, k * chunk).reshape((k, chunk)
                                                        + a.shape[1:]))
@@ -520,6 +531,19 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     with _fetch_span(entry, out):
         return {key: v.reshape((k * chunk,) + v.shape[2:])[:n]
                 for key, v in _host_outputs(out).items()}
+
+
+def _count_lanes(entry: str, n: int, lanes: int, n_devices: int,
+                 chunks: int) -> dict:
+    """Add a dispatch of ``n`` true lanes padded to ``lanes`` to the
+    entry's counters; returns the ``put`` span's shape attrs."""
+    with _LOCK:
+        s = _stats_entry(entry)
+        s["lanes_total"] += n
+        s["padded_lanes_total"] += lanes - n
+        s["devices"] = n_devices
+    return {"lanes": n, "padded_lanes": lanes - n, "devices": n_devices,
+            "chunks": chunks}
 
 
 def _put_bytes(batched, replicated, lanes: int) -> int:

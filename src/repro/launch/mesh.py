@@ -13,20 +13,23 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
-def make_batch_mesh():
-    """1-D ``("batch",)`` mesh over every available device.
+def make_batch_mesh(devices=None):
+    """1-D ``("batch",)`` mesh over ``devices`` (every available device
+    when None).
 
     This is the mesh the batched engines shard their flat batch axis over
     (``repro.engine.population`` flattens D x V x T into one axis and
     splits it across devices with a ``NamedSharding``).  On a single
     device the mesh has one slot and sharding is a transparent no-op.
     """
-    return make_mesh((len(jax.devices()),), ("batch",))
+    devices = jax.devices() if devices is None else list(devices)
+    return make_mesh((len(devices),), ("batch",), devices)
 
 
 def batch_sharding(mesh, ndim: int = 1):

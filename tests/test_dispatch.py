@@ -243,6 +243,32 @@ def test_float64_outputs_cross_as_words_bit_exact(mode, n):
     assert dispatch.stats(name)["wire_bytes_total"] == lanes * (8 + 1) * 8
 
 
+@pytest.mark.parametrize("mode,n,lanes,chunks", [("bucketed", 13, 16, 1),
+                                                  ("bucketed", 16, 16, 1),
+                                                  ("chunked", 13, 16, 4),
+                                                  ("chunked", 6, 8, 2)])
+def test_lane_counters(mode, n, lanes, chunks):
+    """Each dispatch adds its true lanes and its dead ones (the bucket, or
+    ``chunks x chunk``, less N) to the entry's row, records the mesh size,
+    and puts all of it, with the chunk count, on its ``put`` span."""
+    x = np.ones((n, 2), np.float32)
+    name = f"lanes-{mode}-{n}"
+    dispatch.reset_stats()
+    for _ in range(2):
+        dispatch.dispatch_flat(
+            name, lambda x, valid: {"y": x * 2}, [x], mode=mode,
+            config=dispatch.DispatchConfig(max_elements_resident=4))
+    s = dispatch.stats(name)
+    assert (s["lanes_total"], s["padded_lanes_total"], s["devices"]) == (
+        2 * n, 2 * (lanes - n), 1)
+    puts = [r for r in dispatch.spans()[0] if r.name == f"repro.{name}.put"]
+    assert len(puts) == 2
+    assert {k: puts[-1].attrs[k] for k in ("lanes", "padded_lanes",
+                                           "devices", "chunks")} == {
+        "lanes": n, "padded_lanes": lanes - n, "devices": 1,
+        "chunks": chunks}
+
+
 def test_emulated_float64_words_exact_off_subnormals():
     """The arithmetic a TPU runs in place of the bitcast gives the IEEE
     bits of every normal value and infinity (subnormals are flushed by

@@ -115,3 +115,48 @@ def test_property_error_fraction_monotone(vi, dv, extra):
     f_lower_v = d.line_error_fraction(max(v - 0.025, 1.0), 10.0, 10.0)[0]
     assert f_hi_lat <= f_low_lat + 1e-12
     assert f_lower_v >= f_low_lat - 1e-12
+
+
+SEED = 2**31 + 4099
+
+
+def test_resampled_population_is_deterministic_per_seed():
+    a = chips.resampled_population(64, SEED)
+    assert a == chips.resampled_population(64, SEED)
+    assert a != chips.resampled_population(64, SEED + 1)
+    # a longer fleet from the same seed starts with the same rows
+    assert [d.vmin for d in chips.resampled_population(128, SEED)[:64]] \
+        == [d.vmin for d in a]
+
+
+def test_resampled_population_names_and_indices_are_fresh():
+    fleet = chips.resampled_population(1024, SEED)
+    assert len({d.module for d in fleet}) == 1024
+    idx = [d.index for d in fleet]
+    assert len(set(idx)) == 1024 and min(idx) >= len(chips.TABLE7)
+    table7 = {d.module for d in chips.population()}
+    for i, d in enumerate(fleet):
+        row, _, pos = d.module.partition(".r")
+        assert row in table7 and int(pos) == i
+
+
+def test_resampled_population_keeps_the_drawn_row():
+    """Vendor, date, die and V_min are the drawn Table 7 row's; the model
+    re-measures that V_min on the fresh susceptibility field."""
+    rows = {m: (v, date, die, vmin) for m, v, date, die, vmin in chips.TABLE7}
+    fleet = chips.resampled_population(64, SEED)
+    for d in fleet:
+        assert (d.vendor, d.date, d.die, d.vmin) == rows[d.module.split(".")[0]]
+    for d in fleet[:8]:
+        assert chips.measured_vmin(d) == d.vmin, d.module
+
+
+def test_resampled_vendor_mix_follows_table7():
+    """At n = 1,024 each vendor's count lies within 4 binomial sigmas of
+    its Table 7 share (10:12:9 of 31)."""
+    n = 1024
+    fleet = chips.resampled_population(n, SEED)
+    for vendor, k in (("A", 10), ("B", 12), ("C", 9)):
+        p = k / 31
+        got = sum(d.vendor == vendor for d in fleet)
+        assert abs(got - n * p) <= 4 * np.sqrt(n * p * (1 - p)), vendor

@@ -453,3 +453,94 @@ def test_multidevice_controller_and_fleet_mesh_divisible():
         timeout=600, cwd=os.path.dirname(os.path.dirname(__file__)),
         env=dict(os.environ))
     assert "FLEET_SHARDED_OK" in out.stdout, out.stderr[-3000:]
+
+
+RESAMPLE_SEED = 2**31 + 4099
+CANDIDATES = np.array(voltron.CANDIDATE_VOLTAGES + [1.35])
+
+
+def test_ecc_tables_keep_the_fallback_on_a_resampled_fleet():
+    """The ECC stack at the paper's 20 ns ceiling builds a table for every
+    DIMM of a resampled fleet: the 1.35 V fallback is valid everywhere,
+    with finite reliability rows, and no candidate below a vendor's
+    recovery floor is admitted."""
+    from repro.dram import chips
+    dimms = chips.resampled_population(16, RESAMPLE_SEED)
+    grid = engine.DimmGrid.from_dimms(dimms)
+    t = fleet.build_tables(grid, CANDIDATES, policies=fleet.ecc_policies())
+    assert t.modules == tuple(d.module for d in dimms)
+    assert t.stack_name == "min_latency+ecc+hammer"
+    assert t.valid[:, -1].all()
+    for rates in (t.correctable, t.detectable, t.silent):
+        assert np.isfinite(rates[:, -1]).all()
+    floor = np.array([circuit.VENDORS[v].recovery_floor for v in t.vendors])
+    assert not (t.valid & (CANDIDATES[None, :] < floor[:, None])).any()
+
+
+def test_four_device_chunked_fleet_matches_one_device_and_direct():
+    """4 forced host devices: an 8-DIMM resampled fleet streamed as
+    chunks over the ("batch",) mesh equals, bit for bit, the same stream
+    on one device and the direct exact-shape call; the counters record
+    the lanes, the padding and the mesh."""
+    script = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = \\
+            "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import sys
+        sys.path.insert(0, "src")
+        import numpy as np
+        import jax
+        from repro import engine
+        from repro.core import perf_model
+        from repro.dram import chips
+        from repro.engine import controller, dispatch, fleet
+        from repro.launch import mesh as mesh_lib
+        from repro.memsim import workloads
+
+        assert len(jax.devices()) == 4
+        mesh4 = mesh_lib.make_batch_mesh(jax.devices()[:4])
+        mesh1 = mesh_lib.make_batch_mesh(jax.devices()[:1])
+        grid = engine.DimmGrid.from_dimms(
+            chips.resampled_population(8, {RESAMPLE_SEED}))
+        cand = np.array({CANDIDATES.tolist()})
+        tables = fleet.build_tables(grid, cand,
+                                    policies=fleet.ecc_policies(),
+                                    mesh=mesh4)
+        model = perf_model.fit()
+        wb = engine.WorkloadBatch.from_workloads(
+            workloads.homogeneous_workloads()[:5])
+        t = 6
+        phases = 1 + 0.15 * np.random.default_rng(0).uniform(
+            -1, 1, (t, 5 * 8))
+        args = (wb, tables, phases, model.coef_low, model.coef_high, 5.0)
+        # chunks of 16 lanes: 40 lanes stream as 3 chunks, 8 of them dead
+        kw = dict(max_elements_resident=controller.element_cost(t) * 16)
+        dispatch.reset_stats()
+        four = fleet.run_fleet_batched(*args, mesh=mesh4, **kw)
+        s = dispatch.stats("fleet")
+        assert s["chunked_calls"] == 1, s
+        assert (s["lanes_total"], s["padded_lanes_total"],
+                s["devices"]) == (40, 8, 4), s
+        put = [r for r in dispatch.spans()[0] if r.name == "repro.fleet.put"]
+        assert put[-1].attrs["chunks"] == 3, put[-1].attrs
+        one = fleet.run_fleet_batched(*args, mesh=mesh1, **kw)
+        assert dispatch.stats("fleet")["devices"] == 1
+        direct = fleet.run_fleet_batched(*args, dispatch="direct")
+        for f in ("selected_voltages", "perf_loss_pct",
+                  "dram_power_savings_pct", "dram_energy_savings_pct",
+                  "system_energy_savings_pct", "perf_per_watt_gain_pct",
+                  "base_component_j", "pt_component_j"):
+            got = getattr(four, f)
+            for other in (one, direct):
+                want = getattr(other, f)
+                assert got.dtype == want.dtype, f
+                assert np.array_equal(got.view(np.uint8),
+                                      want.view(np.uint8)), f
+        print("OPFLEET_MESH_OK")
+    """)
+    out = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        timeout=600, cwd=os.path.dirname(os.path.dirname(__file__)),
+        env=dict(os.environ))
+    assert "OPFLEET_MESH_OK" in out.stdout, out.stderr[-3000:]

@@ -35,6 +35,7 @@ from repro.kernels.voltage_inject import ops as vi_ops  # noqa: E402
 
 FLEET_BUCKET = 4096          # 77 workloads x 31 DIMMs = 2,387 lanes
 CHAR_BUCKET = 4096           # 31 DIMMs x 19 voltages x 6 temperatures
+OPFLEET_CHUNKS = 20          # 77 workloads x 1,024 DIMMs in chunks of 4,096
 
 
 @pytest.fixture(scope="module")
@@ -210,13 +211,16 @@ def test_controller_scan_holds_the_kernel(one_chip, no_cache):
 
 
 @pytest.mark.parametrize("path", ["test1_chunked", "test1_direct",
-                                  "fleet_bucket"])
+                                  "fleet_bucket", "fleet_chunked"])
 def test_four_chip_mesh_keeps_lanes_local(topo, no_cache, path):
     """On the ("batch",) mesh the dispatch layer and the direct Test-1
     reference run each kernel under shard_map: the Pallas call stays in,
-    and nothing is gathered."""
+    and nothing is gathered.  ``fleet_chunked`` is the operator fleet's
+    stream as the dispatch layer builds it: 20 donated chunks of 4,096
+    lanes, 1,024 a chip, float64 outputs as words."""
     mesh = Mesh(np.array(topo.devices), ("batch",))
     rep_sh = NamedSharding(mesh, PartitionSpec())
+    donate = ()
     if path == "test1_direct":
         batched, statics = _stress_operands()
         four = [np.resize(a, (4,) + a.shape[1:]) for a in batched]
@@ -238,7 +242,7 @@ def test_four_chip_mesh_keeps_lanes_local(topo, no_cache, path):
         fn = dispatch.lane_sharded(dispatch._chunk_fn(kernel, len(args)),
                                     mesh, len(args), len(rep), 1)
         full = (*args, valid, *rep)
-    else:
+    elif path == "fleet_bucket":
         batched, replicated = _fleet_operands(FLEET_BUCKET)
         kernel = functools.partial(
             controller._controller_flat_fn, impl="pallas",
@@ -248,6 +252,19 @@ def test_four_chip_mesh_keeps_lanes_local(topo, no_cache, path):
         args, valid, rep = _lane_specs(batched, replicated, lanes, rep_sh)
         fn = dispatch.lane_sharded(kernel, mesh, len(args), len(rep), 0)
         full = (*args, *rep, valid)
-    text = jax.jit(fn).lower(*full).compile().as_text()
+    else:
+        batched, replicated = _fleet_operands(FLEET_BUCKET)
+        kernel = dispatch._f64_as_words(functools.partial(
+            controller._controller_flat_fn, impl="pallas",
+            solve_cfg=autotune.DEFAULTS["sweep_solve"]))
+        lanes = lambda nd: NamedSharding(
+            mesh, PartitionSpec(None, "batch", *([None] * (nd - 2))))
+        args, valid, rep = _lane_specs(batched, replicated, lanes, rep_sh,
+                                       lead=(OPFLEET_CHUNKS,))
+        fn = dispatch.lane_sharded(dispatch._chunk_fn(kernel, len(args)),
+                                    mesh, len(args), len(rep), 1)
+        full = (*args, valid, *rep)
+        donate = tuple(range(len(full)))
+    text = jax.jit(fn, donate_argnums=donate).lower(*full).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text
