@@ -76,10 +76,15 @@ class KernelConfig:
     """One point in a kernel's tuning space (hashable — rides jit statics
     and dispatch ``statics_key`` tuples).
 
-    ``row_block`` / ``lane_block`` parameterize the Pallas tiling (rows x
-    words for ``voltage_inject``; batch rows x packed feature width for
-    ``sweep_solve``).  ``oracle_chunk`` chunks the jnp oracle's flat batch
-    axis through ``lax.map`` (0 = whole batch, today's behavior);
+    ``row_block`` / ``lane_block`` parameterize the Pallas tiling.  For
+    ``voltage_inject`` they are the block's rows x words.  For
+    ``sweep_solve``, whose samples lie along the lanes, ``row_block`` is
+    the largest number of rows of 128 samples a grid step takes (a batch
+    under one block takes its own rows rounded up to 8:
+    ``sweep_solve.kernel.block_rows``) and ``lane_block`` is the 128
+    samples of a row, the only width the kernel takes.  ``oracle_chunk``
+    chunks the jnp oracle's flat batch axis through ``lax.map`` (0 = whole
+    batch, today's behavior);
     ``unroll`` is the ``sweep_solve`` oracle's fixed-point scan unroll
     (1 = today's behavior).  The per-kernel :data:`DEFAULTS` reproduce the
     pre-tuning module constants bit-for-bit.
@@ -343,7 +348,8 @@ def _interpret_parity(kernel: str, config: KernelConfig) -> None:
             raise AssertionError(f"{config.key()}: interpret parity failed")
         return
     from repro.kernels.sweep_solve import ops as ss_ops
-    args = solve_inputs(2 * config.row_block + 3, 4, seed=7)
+    args = solve_inputs(2 * config.row_block * _ss_kernel.LANES + 3, 4,
+                        seed=7)
     ref = ss_ops.solve(*args, impl="reference")
     got = ss_ops.solve(*args, impl="pallas_interpret", config=config)
     _assert_parity(kernel, got, ref, f"{config.key()} interpret")
@@ -366,10 +372,10 @@ def candidate_configs(kernel: str, backend: str | None = None,
                     [(r, w) for r in (8, 16, 32) for w in (512, 1024, 2048)])
             return tuple(rep(row_block=r, lane_block=w) for r, w in grid
                          if (r, w) != (8, 1024))
-        grid = ([(16, 128)] if smoke else
-                [(r, lanes) for r in (8, 16, 32) for lanes in (128, 256)])
-        return tuple(rep(row_block=r, lane_block=lanes) for r, lanes in grid
-                     if (r, lanes) != (8, 128))
+        cands = tuple(rep(row_block=r, lane_block=_ss_kernel.LANES)
+                      for r in (8, 16, 32, 64)
+                      if r != DEFAULTS[kernel].row_block)
+        return cands[:1] if smoke else cands
     if kernel == "voltage_inject":
         chunks = (64, 128) if smoke else (32, 64, 128, 256)
         return tuple(rep(oracle_chunk=c) for c in chunks)
@@ -389,8 +395,9 @@ def candidate_cost(config: KernelConfig, shape, *, nplanes: int = 2,
                    iters: int = _ss_ref.DEFAULT_ITERS) -> tuple:
     """(flops, bytes) a candidate must move at minimum, after the padding
     its blocks/chunks force — the roofline-pruning inputs.  Oracle
-    candidates pad only the leading axis (to the chunk); Pallas candidates
-    pad both axes to their tile grid."""
+    candidates pad only the leading axis (to the chunk); ``voltage_inject``
+    Pallas candidates pad both axes to their tile grid, ``sweep_solve``'s
+    the batch to whole blocks of rows of 128 samples."""
     if config.kernel == "voltage_inject":
         r, w = int(shape[0]), int(shape[1])
         if config.oracle_chunk:
@@ -401,13 +408,15 @@ def candidate_cost(config: KernelConfig, shape, *, nplanes: int = 2,
         # data + rand_word + nplanes + output planes of u32, + the prob row
         return 8.0 * r2 * w2, float((nplanes + 3) * r2 * w2 * 4 + r2 * 4)
     b, c = int(shape[0]), int(shape[1])
-    b2 = _ceil_to(b, config.oracle_chunk or config.row_block)
-    width = (3 * c + 8) if config.oracle_chunk or config.unroll > 1 \
-        or config.lane_block == 0 else config.lane_block
-    if jax.default_backend() in ("tpu", "gpu") and not config.oracle_chunk:
-        width = config.lane_block
-    # ~40 vector ops per damped iteration over the padded [B2, C] batch
-    return 40.0 * b2 * c * iters, 2.0 * b2 * width * 4
+    if jax.default_backend() in ("tpu", "gpu"):
+        # the lane-dense kernel pads B to whole blocks of row_block x 128
+        b2 = _ceil_to(b, _ss_kernel.block_rows(b, config.row_block)
+                      * _ss_kernel.LANES)
+    else:
+        b2 = _ceil_to(b, config.oracle_chunk or 1)
+    # ~40 vector ops per damped iteration over the padded [B2, C] batch;
+    # the 3C + 8 feature planes in and the C + 2 output planes out
+    return 40.0 * b2 * c * iters, float((3 * c + 8 + c + 2) * 4 * b2)
 
 
 @dataclasses.dataclass(frozen=True)

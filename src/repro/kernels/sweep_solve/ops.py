@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import hw
 from repro.kernels.sweep_solve import kernel as _kernel
@@ -17,31 +18,31 @@ from repro.kernels.sweep_solve import ref as _ref
 
 def pack_features(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
                   t_rcd, t_rp, t_ras, transfer_ns, peak_bw_gbps,
-                  row_block=None, lanes=None):
-    """Pack the SoA sample batch into the kernel's [B, lanes] feature rows,
-    padding B up to the kernel's row block with benign (all-ones-ish) rows
-    at the standard channel timings (the named ``hw`` constants)."""
-    row_block = row_block or _kernel.ROW_BLOCK
-    lanes = lanes or _kernel.LANES
-    per_core = [mpki, ipc_base, mlp]                     # [B, C] each
-    scalars = [row_hit, eff_banks, write_mult, t_rcd, t_rp, t_ras,
-               transfer_ns, peak_bw_gbps]                # [B] each
+                  row_block=None):
+    """Lay the SoA sample batch out lane-dense for the kernel:
+    float32[3C + 8, Bp/128, 128], one plane a feature (mpki, ipc_base and
+    mlp as C planes each, then the 8 per-sample scalars), samples along
+    the lanes.  B pads up to whole blocks of ``row_block`` rows of 128
+    (default: ``kernel.block_rows(B)``) with benign samples (ipc_base, mlp,
+    banks, write multiplier = 1, the standard channel timings: the named
+    ``hw`` constants)."""
     b, c = mpki.shape
-    cols = [jnp.asarray(x, jnp.float32) for x in per_core]
-    cols += [jnp.asarray(x, jnp.float32)[:, None] for x in scalars]
-    feat = jnp.concatenate(cols, axis=1)
-    feat = jnp.pad(feat, ((0, 0), (0, lanes - feat.shape[1])))
-    pad_rows = (-b) % row_block
-    if pad_rows:
-        benign = jnp.zeros((pad_rows, lanes), jnp.float32)
-        benign = benign.at[:, c:3 * c].set(1.0)          # ipc_base, mlp = 1
-        benign = benign.at[:, 3 * c + 1].set(1.0)        # eff_banks = 1
-        benign = benign.at[:, 3 * c + 2].set(1.0)        # write_mult = 1
-        benign = benign.at[:, 3 * c + 3:3 * c + 6].set(hw.T_RCD_STD)
-        benign = benign.at[:, 3 * c + 6].set(hw.LINE_TRANSFER_NS)
-        benign = benign.at[:, 3 * c + 7].set(hw.PEAK_BW_GBPS)
-        feat = jnp.concatenate([feat, benign], axis=0)
-    return feat
+    row_block = row_block or _kernel.block_rows(b)
+    planes = [jnp.asarray(x, jnp.float32).T for x in (mpki, ipc_base, mlp)]
+    planes += [jnp.asarray(x, jnp.float32)[None]
+               for x in (row_hit, eff_banks, write_mult, t_rcd, t_rp, t_ras,
+                         transfer_ns, peak_bw_gbps)]
+    feat = jnp.concatenate(planes, axis=0)               # [3C + 8, B]
+    block = row_block * _kernel.LANES
+    pad = (-b) % block
+    if pad:
+        fill = np.array([0.0] * c + [1.0] * (2 * c)
+                        + [0.0, 1.0, 1.0] + [hw.T_RCD_STD] * 3
+                        + [hw.LINE_TRANSFER_NS, hw.PEAK_BW_GBPS], np.float32)
+        benign = jnp.broadcast_to(jnp.asarray(fill)[:, None],
+                                  (fill.size, pad))
+        feat = jnp.concatenate([feat, benign], axis=1)
+    return feat.reshape(feat.shape[0], -1, _kernel.LANES)
 
 
 def _solve_ref_chunked(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
@@ -49,7 +50,7 @@ def _solve_ref_chunked(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
                        *, t_cl, iters, unroll, chunk):
     """Oracle with a tunable batch chunk: the flat axis runs through
     ``lax.map`` over ``chunk``-sample slabs.  The pad samples are the same
-    benign rows ``pack_features`` appends (ipc_base/mlp/banks/write = 1,
+    benign samples ``pack_features`` appends (ipc_base/mlp/banks/write = 1,
     standard channel timings), every sample solves independently, and the
     pads are sliced back off — so chunking changes XLA's working-set shape
     only.  Per-sample values can drift <=1e-6 from the unchunked oracle
@@ -87,9 +88,10 @@ def solve(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
     in ``ref.solve_ref``.
 
     ``config`` is an optional ``autotune.KernelConfig``: ``unroll`` and a
-    nonzero ``oracle_chunk`` retune the reference path, blocks/lanes retile
-    the Pallas paths.  ``None`` (and the default config) reproduce the
-    historical behavior exactly.
+    nonzero ``oracle_chunk`` retune the reference path; ``row_block`` is the
+    Pallas path's largest block (rows of 128 samples a grid step, see
+    ``kernel.block_rows``) and ``lane_block`` must be 128.  ``None`` is the
+    default config.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
@@ -107,19 +109,20 @@ def solve(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
                               unroll=unroll)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown impl {impl!r}")
-    row_block = config.row_block if config is not None else None
-    lanes = config.lane_block if config is not None else None
     b, c = mpki.shape
+    if config is None:
+        row_block, lanes = _kernel.block_rows(b), _kernel.LANES
+    else:
+        row_block = _kernel.block_rows(b, config.row_block)
+        lanes = config.lane_block
     feat = pack_features(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
                          t_rcd, t_rp, t_ras, transfer_ns, peak_bw_gbps,
-                         row_block=row_block, lanes=lanes)
+                         row_block=row_block)
     out = _kernel.solve_pallas(feat, c, iters, t_cl,
                                interpret=(impl == "pallas_interpret"),
-                               row_block=row_block or _kernel.ROW_BLOCK,
-                               lanes=lanes or _kernel.LANES)
-    ipc = out[:b, 0:c]
-    loaded = out[:b, c]
-    util = out[:b, c + 1]
-    return _ref.finalize(ipc, loaded, util, jnp.asarray(mpki, jnp.float32),
+                               row_block=row_block, lanes=lanes)
+    out = out.reshape(c + 2, -1)
+    return _ref.finalize(out[:c, :b].T, out[c, :b], out[c + 1, :b],
+                         jnp.asarray(mpki, jnp.float32),
                          jnp.asarray(ipc_base, jnp.float32),
                          jnp.asarray(row_hit, jnp.float32))

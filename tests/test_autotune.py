@@ -174,6 +174,32 @@ class TestSolveConfigParity:
                                        err_msg=k)
 
 
+class TestSweepSolveSearch:
+    """The lane-dense ``sweep_solve`` search space and its roofline cost."""
+
+    def test_candidates_vary_rows_at_128_lanes(self):
+        cands = autotune.candidate_configs("sweep_solve", "tpu")
+        assert {c.row_block for c in cands} == \
+            {8, 16, 32, 64} - {ss_kernel.ROW_BLOCK}
+        assert {c.lane_block for c in cands} == {ss_kernel.LANES}
+        smoke = autotune.candidate_configs("sweep_solve", "tpu", smoke=True)
+        assert len(smoke) == 1 and smoke[0] in cands
+
+    @pytest.mark.parametrize("b,c,row_block,padded", [
+        (5000, 4, 16, 3 * 16 * 128),     # three blocks of 2,048 samples
+        (100, 1, 64, 8 * 128),           # under one block: 8 rows
+        (4096, 4, 32, 4096),             # one whole block
+    ])
+    def test_cost_counts_lane_dense_padding(self, monkeypatch, b, c,
+                                            row_block, padded):
+        monkeypatch.setattr(autotune.jax, "default_backend", lambda: "tpu")
+        cfg = dataclasses.replace(autotune.DEFAULTS["sweep_solve"],
+                                  row_block=row_block)
+        flops, bytes_ = autotune.candidate_cost(cfg, (b, c))
+        assert bytes_ == padded * (3 * c + 8 + c + 2) * 4
+        assert flops == 40.0 * padded * c * ss_ref.DEFAULT_ITERS
+
+
 class TestTuner:
     def test_roofline_prunes_oversized_candidate(self):
         """A chunk far above the batch pads the whole plane up — its bound

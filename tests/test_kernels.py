@@ -1,9 +1,12 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracles."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import autotune
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.sweep_solve import kernel as sweep_kernel
@@ -70,12 +73,12 @@ def _solve_args(b, c, seed=0):
 
 
 class TestSweepSolveEdges:
-    """Interpret-mode edge cases of the packed-feature batch layout."""
+    """Interpret-mode edge cases of the lane-dense feature layout."""
 
     @pytest.mark.parametrize("b", [1, 5, 13])
     def test_batch_not_multiple_of_row_block(self, b):
-        """W*P that does not tile the 8-row packing (and the W=P=1 case,
-        b=1) pads with benign rows that must not leak into results."""
+        """W*P that does not fill a row of 128 lanes (and the W=P=1 case,
+        b=1) pads with benign samples that must not leak into results."""
         args = _solve_args(b, 4, seed=b)
         ref = sweep_ops.solve(*args, impl="reference")
         pal = sweep_ops.solve(*args, impl="pallas_interpret")
@@ -93,11 +96,45 @@ class TestSweepSolveEdges:
         np.testing.assert_allclose(np.asarray(pal["ipc"]),
                                    np.asarray(ref["ipc"]), rtol=1e-6)
 
+    @pytest.mark.parametrize("b,c,row_block", [
+        (5, 1, None),                        # under one block (C = 4
+                                             # at B = 5: the test above)
+        (1000, 4, None), (1000, 1, None),    # not a multiple of 128
+        (2 * 16 * 128 + 77, 4, 16),          # 3 grid steps of 16 rows
+        (2 * 8 * 128 + 77, 1, 8),            # 3 grid steps of 8 rows
+    ])
+    def test_lane_dense_matches_ref(self, b, c, row_block):
+        """The lane-dense kernel against the oracle at rtol 1e-6 on every
+        output.  ``stall_frac`` is ``1 - ipc / ipc_base``: near zero the
+        subtraction turns an ipc ulp into a large relative error, so it
+        also gets an absolute 1e-6 (ipc's relative tolerance)."""
+        args = _solve_args(b, c, seed=b + c)
+        cfg = None
+        if row_block is not None:
+            cfg = dataclasses.replace(autotune.DEFAULTS["sweep_solve"],
+                                      row_block=row_block)
+            assert sweep_kernel.block_rows(b, row_block) == row_block
+            assert -(-b // (row_block * 128)) >= 3
+        ref = sweep_ops.solve(*args, impl="reference")
+        pal = sweep_ops.solve(*args, impl="pallas_interpret", config=cfg)
+        for k in ref:
+            assert pal[k].shape == ref[k].shape, k
+            np.testing.assert_allclose(
+                np.asarray(pal[k]), np.asarray(ref[k]), rtol=1e-6,
+                atol=1e-6 if k == "stall_frac" else 0.0, err_msg=k)
+
     def test_pack_features_pads_to_lane_block(self):
-        feat = sweep_ops.pack_features(*_solve_args(5, 4))
-        assert feat.shape == (8, sweep_kernel.LANES)       # 5 -> ROW_BLOCK
-        # benign pad rows keep the fixed point stable (no NaN/inf)
-        out = sweep_kernel.solve_pallas(feat, 4, interpret=True)
+        """Samples lie along the 128 lanes, one plane a feature; a batch
+        under one block pads to a single 8-row sublane tile of samples."""
+        args = _solve_args(5, 4)
+        feat = sweep_ops.pack_features(*args)
+        assert feat.shape == (3 * 4 + 8, 8, sweep_kernel.LANES)
+        planes = np.asarray(feat).reshape(feat.shape[0], -1)
+        np.testing.assert_array_equal(planes[:4, :5], np.asarray(args[0]).T)
+        np.testing.assert_array_equal(planes[12, :5], np.asarray(args[3]))
+        # benign pad samples keep the fixed point stable (no NaN/inf)
+        out = sweep_kernel.solve_pallas(feat, 4, interpret=True, row_block=8)
+        assert out.shape == (4 + 2, 8, sweep_kernel.LANES)
         assert np.isfinite(np.asarray(out)).all()
 
     def test_solve_rejects_unknown_impl(self):
@@ -105,10 +142,33 @@ class TestSweepSolveEdges:
             sweep_ops.solve(*_solve_args(2, 4), impl="banana")
 
     def test_solve_pallas_rejects_untiled_shape(self):
-        with pytest.raises(ValueError):
-            sweep_kernel.solve_pallas(jnp.zeros((5, 128), jnp.float32), 4)
-        with pytest.raises(ValueError):
-            sweep_kernel.solve_pallas(jnp.zeros((8, 64), jnp.float32), 4)
+        """The batch axis must be tiled by row_block x 128 samples, the
+        features must be the 3C + 8 planes, and the lanes stay 128."""
+        feat = jnp.zeros((20, 8, 128), jnp.float32)
+        with pytest.raises(ValueError):        # 8 rows, blocks of 16
+            sweep_kernel.solve_pallas(feat, 4, row_block=16)
+        with pytest.raises(ValueError):        # not a sublane multiple
+            sweep_kernel.solve_pallas(jnp.zeros((20, 12, 128), jnp.float32),
+                                      4, row_block=12)
+        with pytest.raises(ValueError):        # 64 lanes of samples
+            sweep_kernel.solve_pallas(jnp.zeros((20, 8, 64), jnp.float32),
+                                      4, row_block=8)
+        with pytest.raises(ValueError):        # C = 1 planes for C = 4
+            sweep_kernel.solve_pallas(jnp.zeros((11, 8, 128), jnp.float32),
+                                      4, row_block=8)
+        with pytest.raises(ValueError):        # a wrong lane_block
+            sweep_kernel.solve_pallas(feat, 4, row_block=8, lanes=256)
+
+    def test_block_rows_adapts_to_the_batch(self):
+        """A batch under one block takes its own rows of 128 rounded up
+        to 8; a larger one takes the full block."""
+        rb = sweep_kernel.ROW_BLOCK
+        assert sweep_kernel.block_rows(1) == 8
+        assert sweep_kernel.block_rows(1000) == min(rb, 8)
+        assert sweep_kernel.block_rows(1025) == min(rb, 16)
+        assert sweep_kernel.block_rows(rb * 128 * 3) == rb
+        assert sweep_kernel.block_rows(16384, 64) == 64
+        assert sweep_kernel.block_rows(4096, 64) == 32
 
     def test_empty_candidate_fallback_to_nominal(self):
         """Algorithm 1 with an unreachable loss target selects the 1.35 V

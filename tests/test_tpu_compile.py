@@ -10,6 +10,7 @@ memory, partitioning) fails here.  The two Pallas kernels must appear as a
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import dataclasses
 import functools
 import os
 import sys
@@ -96,6 +97,25 @@ def test_sweep_solve_compiles_at_the_fleet_bucket(one_chip, no_cache):
     fn = jax.jit(lambda *a: ss_ops.solve(*a, impl="pallas"))
     compiled = fn.lower(*([per_core] * 3 + [per_lane] * 8)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b,c,row_block", [
+    (16384, 1, None),          # the alone solve: W x C samples of one core
+    (1024, 4, None),           # an opfleet.x4 chunk's share of one chip
+    (4096, 4, 8), (4096, 4, 16), (4096, 4, 32), (4096, 4, 64),
+])
+def test_sweep_solve_compiles_lane_dense(one_chip, no_cache, b, c,
+                                         row_block):
+    """The lane-dense kernel at the controller scan's shapes, with the
+    default adaptive block and with each block the autotuner searches."""
+    cfg = None if row_block is None else dataclasses.replace(
+        autotune.DEFAULTS["sweep_solve"], row_block=row_block)
+    per_core = _sds((b, c), jnp.float32, one_chip)
+    per_lane = _sds((b,), jnp.float32, one_chip)
+    fn = jax.jit(lambda *a: ss_ops.solve(*a, impl="pallas", config=cfg))
+    compiled = fn.lower(*([per_core] * 3 + [per_lane] * 8)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info["ipc"].shape == (b, c)
 
 
 def _characterize_specs(b, sharding):
