@@ -7,7 +7,8 @@ cell's intervals, with per-lane memory-intensity phases that the
 benchmark draws from the run's seed and the job's index: every job does
 the same work on different phases.
 
-``check`` draws lanes of the window's jobs from the seed and runs them
+The window keeps ``KEEP_JOBS`` whole jobs' outputs, drawn from the seed.
+``check`` draws lanes of the kept jobs from the seed and runs them
 through the plain reference in ``bench/ref/fleet.py`` on the host, with
 its own tables and its own fitted predictor: the selected voltage of
 every interval must match, and the five Fig. 14/17 metrics must lie
@@ -20,6 +21,17 @@ import numpy as np
 METRICS = ("perf_loss_pct", "dram_power_savings_pct",
            "dram_energy_savings_pct", "system_energy_savings_pct",
            "perf_per_watt_gain_pct")
+# whole outputs the window keeps for the check: few, since the jobs run
+# while the keep fills are slower (on a TPU v5e host, keeping 64 slowed
+# jobs 24-63 of each window by 6%)
+KEEP_JOBS = 8
+
+
+def job_lanes(outputs: list, picks) -> list:
+    """``[(job, lane), ...]`` of ``picks`` ``[(position, lane), ...]``
+    among the window's kept ``outputs``: the job index that the
+    reference recomputes the lane's phases from."""
+    return [(outputs[pos]["job"], lane) for pos, lane in picks]
 
 
 def phases(rng: np.random.Generator, n_intervals: int, n_lanes: int,
@@ -34,6 +46,7 @@ def phases(rng: np.random.Generator, n_intervals: int, n_lanes: int,
 
 class Job:
     entries = ("fleet",)
+    keep_jobs = KEEP_JOBS
 
     def __init__(self, config: dict, params: dict, seed: int):
         import jax
@@ -117,6 +130,8 @@ class Job:
                                   lo, hi, dtype=dtype)
 
     def picks(self, n_jobs: int) -> list:
+        """``check_lanes`` (position, lane) pairs over ``n_jobs`` kept
+        outputs, drawn from the seed."""
         n = min(self.params["check_lanes"], n_jobs * self.lanes())
         flat = self.check_rng.choice(n_jobs * self.lanes(), n, replace=False)
         return [divmod(int(f), self.lanes()) for f in sorted(flat)]
@@ -129,7 +144,8 @@ class Job:
                 ("metric_gap_pp", gap, self.params["limit_pp"])]
 
     def gathered(self, outputs: list, picks) -> dict:
-        """The program's values at ``picks``, shaped as the reference's."""
+        """The program's values at ``picks`` (positions among the kept
+        ``outputs``), shaped as the reference's."""
         cand = np.asarray(self.tables.cand_v, np.float64)
         d_ = len(self.config["modules"])
         out = {m: [] for m in METRICS}
@@ -147,12 +163,12 @@ class Job:
     def check(self, outputs: list) -> list:
         picks = self.picks(len(outputs))
         return self.compare(self.gathered(outputs, picks),
-                            self.reference(picks))
+                            self.reference(job_lanes(outputs, picks)))
 
     def control(self, outputs: list) -> list:
         """The reference in bfloat16 (float32 as stated) in the program's
         place, at the picks ``check`` would draw."""
         import jax.numpy as jnp
-        picks = self.picks(len(outputs))
-        return self.compare(self.reference(picks, jnp.bfloat16),
-                            self.reference(picks))
+        at = job_lanes(outputs, self.picks(len(outputs)))
+        return self.compare(self.reference(at, jnp.bfloat16),
+                            self.reference(at))

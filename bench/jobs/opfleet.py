@@ -10,27 +10,33 @@ mix x DIMM lane on that mesh, with per-lane memory-intensity phases drawn
 from the seed and the job's index (``bench/jobs/fleet.py``); past the top
 bucket the dispatch layer streams the lanes as chunks.
 
-``check`` draws lanes of the window's jobs from the seed, as many from
-each chip's share of a chunk as from any other, resamples the fleet with
-the reference's own code (``bench/ref/opfleet.py``), builds the picked
-DIMMs' ECC tables there, and runs the picked lanes through the reference
-controller: every table field of a picked DIMM must be exact, the
-selected voltage of every interval must match, and the five Fig. 14/17
-metrics must lie within the cell's limit in percentage points.
+The window keeps ``KEEP_JOBS`` whole jobs' outputs (about 26 MB each),
+drawn from the seed.  ``check`` draws lanes of the kept jobs from the
+seed, as many from each chip's share of a chunk as from any other,
+resamples the fleet with the reference's own code
+(``bench/ref/opfleet.py``), builds the picked DIMMs' ECC tables there,
+and runs the picked lanes through the reference controller: every table
+field of a picked DIMM must be exact, the selected voltage of every
+interval must match, and the five Fig. 14/17 metrics must lie within the
+cell's limit in percentage points.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench.jobs.fleet import METRICS, phases
+from bench.jobs.fleet import METRICS, job_lanes, phases
 from bench.jobs.tables import _gap
 
 TABLE_FIELDS = ("valid", "timings", "lat_feat")
 RATES = ("correctable", "detectable", "silent")
+# whole outputs the window keeps for the check (about 26 MB each): few,
+# as in bench/jobs/fleet.py
+KEEP_JOBS = 4
 
 
 class Job:
     entries = ("fleet",)
+    keep_jobs = KEEP_JOBS
 
     def __init__(self, config: dict, params: dict, seed: int):
         import jax
@@ -98,8 +104,8 @@ class Job:
         return (np.asarray(lanes) % b) // (b // len(self.mesh.devices.flat))
 
     def picks(self, n_jobs: int) -> list:
-        """``check_lanes`` (job, lane) pairs, an equal number from each
-        chip's share of the window's lanes."""
+        """``check_lanes`` (position, lane) pairs over ``n_jobs`` kept
+        outputs, an equal number from each chip's share of their lanes."""
         n_dev = len(self.mesh.devices.flat)
         shard = self.shard_of(np.arange(self.lanes()))
         out = []
@@ -184,8 +190,8 @@ class Job:
                 ("metric_gap_pp", gap, p["limit_pp"])]
 
     def gathered(self, outputs: list, picks) -> tuple:
-        """The program's tables and values at ``picks``, shaped as the
-        reference's."""
+        """The program's tables and values at ``picks`` (positions among
+        the kept ``outputs``), shaped as the reference's."""
         t = self.tables
         cand = np.asarray(t.cand_v, np.float64)
         d_ = self.config["n_dimms"]
@@ -210,13 +216,13 @@ class Job:
     def check(self, outputs: list) -> list:
         picks = self.picks(len(outputs))
         return self.compare(self.gathered(outputs, picks),
-                            self.reference(picks))
+                            self.reference(job_lanes(outputs, picks)))
 
     def control(self, outputs: list) -> list:
         """The reference controller in bfloat16 (float32 as stated) in the
         program's place, at the picks ``check`` would draw; the tables
         are the reference's own on both sides."""
         import jax.numpy as jnp
-        picks = self.picks(len(outputs))
-        return self.compare(self.reference(picks, jnp.bfloat16),
-                            self.reference(picks))
+        at = job_lanes(outputs, self.picks(len(outputs)))
+        return self.compare(self.reference(at, jnp.bfloat16),
+                            self.reference(at))

@@ -6,10 +6,10 @@ at the cell's voltages and hammer counts, both at the configuration's
 geometry.  Job ``i`` of a run draws its Test-1 seed from the run's seed,
 so every job does the same work on different random bits.
 
-``check`` recomputes every lane of a few jobs of the window (drawn from
-the seed) with the plain reference in ``bench/ref/stress.py`` and counts
-the lanes whose bit-error count, erroneous-line count or error-row map
-differ: the comparison is exact.
+The window keeps ``check_jobs`` jobs' outputs, drawn from the seed, and
+``check`` recomputes every lane of them with the plain reference in
+``bench/ref/stress.py`` and counts the lanes whose bit-error count,
+erroneous-line count or error-row map differ: the comparison is exact.
 """
 from __future__ import annotations
 
@@ -29,6 +29,8 @@ class Job:
         from repro import engine
         self.jax = jax
         self.config, self.params = config, params
+        self.seed = seed
+        self.keep_jobs = params["check_jobs"]
         self.grid = engine.DimmGrid.from_population(config["modules"])
         rng = np.random.default_rng(seed)
         self.seed0 = int(rng.integers(0, SEED_SPAN))
@@ -127,7 +129,8 @@ class Job:
         return out
 
     def picks(self, n_jobs: int) -> list:
-        """Indices of the window's jobs to check, drawn from the seed."""
+        """Positions among the window's kept outputs to check, drawn from
+        the seed."""
         return sorted(int(i) for i in self.check_rng.choice(
             n_jobs, min(self.params["check_jobs"], n_jobs), replace=False))
 

@@ -7,10 +7,12 @@ the ECC-aware safe-voltage tables over the Algorithm-1 candidates
 DIMMs in an order drawn from the run's seed: the same work, laid out
 differently on the batch axis.
 
-``check`` recomputes a few DIMMs' characterization drawn from the seed
-with the plain reference on the host (largest absolute gap, float64) and
-every DIMM's table (validity, timings and latency features exactly;
-hammer margins and ECC rates as their largest gap).
+The window keeps one job's output, drawn from the seed (each holds a
+134.7 MB characterization).  ``check`` recomputes a few of its DIMMs'
+characterization, drawn from the seed, with the plain reference on the
+host (largest absolute gap, float64) and every DIMM's table (validity,
+timings and latency features exactly; hammer margins and ECC rates as
+their largest gap).
 """
 from __future__ import annotations
 
@@ -23,6 +25,8 @@ CHAR_FIELDS = ("line_error_fraction", "ber", "t_rcd_min", "t_rp_min",
 class Job:
     entries = ("characterize", "min_latency", "beat_error")
     metric = "requalify_s"
+    # the check reads one job: the window keeps one, drawn from the seed
+    keep_jobs = 1
 
     def __init__(self, config: dict, params: dict, seed: int):
         import jax
@@ -92,6 +96,8 @@ class Job:
         return self._ref_tables
 
     def picks(self, n_jobs: int) -> list:
+        """``[(position, module), ...]``: a kept output and DIMMs of it,
+        drawn from the seed."""
         job = int(self.check_rng.integers(n_jobs))
         mods = self.check_rng.choice(self.config["modules"],
                                      self.params["check_dimms"],

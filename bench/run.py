@@ -9,9 +9,10 @@ and ends at a job boundary.  With ``--trace 0`` the last line of standard
 output holds the cell's end-to-end metrics; with ``--trace 1`` the window
 runs under the JAX profiler and the line holds the per-layer metrics, read
 by ``bench/metrics/<metric>.py``, with the device's busy time and a
-breakdown.  Either way the outputs of the window are then checked against
-the plain reference, and every number compared is printed beside its
-limit, last on standard error and last in the result line.
+breakdown.  Either way a sample of the window's outputs, drawn from the
+seed as the window runs, is then checked against the plain reference,
+and every number compared is printed beside its limit, last on standard
+error and last in the result line.
 
 The run fails, and prints no result, when JAX finds no TPU or fewer chips
 than the cell asks for.  JAX's compilation cache lives in
@@ -75,32 +76,95 @@ def dispatch_totals() -> tuple:
             sum(s["compiles"] for s in st.values()))
 
 
+class Reservoir:
+    """A uniform sample of at most ``k`` items of a stream of unknown
+    length (Vitter's Algorithm R): item ``t`` (from 0) is kept with
+    probability ``k / (t + 1)``, in the place of a kept item drawn
+    uniformly.  Which indices it keeps depends on ``rng`` and the number
+    of items offered alone."""
+
+    def __init__(self, k: int, rng):
+        if k < 1:
+            raise ValueError(f"a reservoir keeps at least one item, not {k}")
+        self.k, self.rng, self.seen = k, rng, 0
+        self.items = []
+
+    def offer(self, index: int, item) -> None:
+        if self.seen < self.k:
+            self.items.append((index, item))
+        else:
+            slot = int(self.rng.integers(self.seen + 1))
+            if slot < self.k:
+                self.items[slot] = (index, item)
+        self.seen += 1
+
+    def kept(self) -> list:
+        """The kept items in the order they were offered."""
+        return [item for _, item in sorted(self.items, key=lambda t: t[0])]
+
+
+def keep_rng(seed: int):
+    """The reservoir's generator: the run seed's first child stream,
+    apart from the streams the jobs draw their inputs and checks from."""
+    import numpy as np
+    return np.random.default_rng(np.random.SeedSequence(seed).spawn(1)[0])
+
+
+def _rss_bytes():
+    """Resident set size of this process, or None where /proc has none."""
+    try:
+        with open("/proc/self/statm") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def window_notes(jobs: list, rss0, rss1) -> dict:
+    """Per-job wall times (p50, p90, max) and the process's RSS growth
+    over the window: notes that show an unsteady window's cause."""
+    import numpy as np
+    took = np.array([r["end"] - r["start"] for r in jobs])
+    return {"jobs": len(jobs),
+            "job_p50_s": float(np.percentile(took, 50)),
+            "job_p90_s": float(np.percentile(took, 90)),
+            "job_max_s": float(took.max()),
+            "rss_growth_mb": (None if rss0 is None or rss1 is None
+                              else (rss1 - rss0) / 2**20)}
+
+
 def batch_window(job, seconds: float) -> dict:
     """Jobs back to back until ``seconds`` have passed; the window ends
     at a job boundary.  Each job ends in ``block_until_ready`` on its
     outputs (the program's dispatch waits for them).  The window's time
     per job is reported under the job's ``metric`` (``job_s`` unless the
-    job kind names another)."""
+    job kind names another).
+
+    Only ``job.keep_jobs`` whole outputs outlive their job: a uniform
+    sample of the window's jobs drawn from the run's seed
+    (:class:`Reservoir`), which ``job.check`` then draws inside; the
+    rest are dropped when offered or pushed out."""
     import jax
-    jobs, outputs = [], []
+    keep = Reservoir(job.keep_jobs, keep_rng(job.seed))
+    jobs = []
+    rss0 = _rss_bytes()
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.window"):
         while True:
             d0, _ = dispatch_totals()
             s = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.job"):
-                outputs.append(job.run(len(outputs)))
+                keep.offer(len(jobs), job.run(len(jobs)))
             e = time.perf_counter()
             d1, _ = dispatch_totals()
             jobs.append({"start": s, "end": e, "dispatch_s": d1 - d0})
             if e - t0 >= seconds:
                 break
     window_s = time.perf_counter() - t0
-    return {"window_s": window_s, "jobs": jobs, "outputs": outputs,
+    return {"window_s": window_s, "jobs": jobs, "outputs": keep.kept(),
             "end_to_end": {getattr(job, "metric", "job_s"):
                            stats.job_s(window_s, len(jobs))},
             "attempted": len(jobs), "failed": 0,
-            "notes": {"jobs": len(jobs)}}
+            "notes": window_notes(jobs, rss0, _rss_bytes())}
 
 
 def _device_info(devices) -> dict:
