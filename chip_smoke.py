@@ -55,7 +55,6 @@ from repro import engine, hw  # noqa: E402
 from repro.core import perf_model, voltron  # noqa: E402
 from repro.dram import test1 as scalar_test1  # noqa: E402
 from repro.engine import dispatch, fleet, service, test1  # noqa: E402
-from repro.kernels import autotune  # noqa: E402
 from repro.launch import fleet_serve  # noqa: E402
 from repro.memsim import workloads  # noqa: E402
 
@@ -514,7 +513,6 @@ def main(argv=None) -> int:
         print(f"chip_smoke: --chips {args.chips} needs exactly that many "
               f"devices; JAX sees {len(devices)}", file=sys.stderr)
         return 1
-    autotune.disable()          # default kernel configs, nothing from disk
     dev = devices[0]
     print(f"device: {dev.platform} {dev.device_kind} x{len(devices)}; "
           f"compile cache {cache}")

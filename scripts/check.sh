@@ -1,11 +1,12 @@
 #!/usr/bin/env bash
-# One-step verification: tier-1 test suite + a fast benchmark smoke.
+# One-step verification: the tier-1 test suite (speed is judged on the
+# chip by bench/, not here).
 #   scripts/check.sh            # everything
 #   scripts/check.sh -m 'not slow'   # extra pytest args pass through
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-# JAX persistent compilation cache: repeated check/benchmark runs pay XLA
+# JAX persistent compilation cache: repeated check runs pay XLA
 # compilation once per machine (thresholds dropped so every kernel persists)
 export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/artifacts/jax_cache}"
 export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0
@@ -25,44 +26,3 @@ if [[ "${COVERAGE:-0}" == "1" ]]; then
 else
     python -m pytest -x -q "$@"
 fi
-
-# fast smoke: the Voltron-vs-MemDVFS controller figure through the batched
-# engine (run.py exits nonzero if the figure function fails)
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.run fig14
-
-# perf-trajectory artifacts: batched Test-1 speedup vs the per-bank scalar
-# loop, and the shape-stable dispatch stream/megabatch acceptance (both
-# exit nonzero if parity breaks)
-mkdir -p artifacts
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.test1_bench artifacts/BENCH_test1.json
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.dispatch_bench artifacts/BENCH_dispatch.json
-
-# fleet-scale Voltron: W x D controller cross-product through the dispatch
-# layer (exits nonzero if per-lane parity or shape-stable reuse breaks)
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.fleet_bench artifacts/BENCH_fleet.json
-
-# per-component power: batched six-component breakdown vs the scalar
-# loop + heterogeneous-fleet shape stability (exits nonzero if component
-# sums drift from the legacy totals or selections depend on the model)
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.energy_bench artifacts/BENCH_energy.json
-
-# streaming fleet service: coalesced open-loop throughput vs the
-# request-at-a-time loop + admission acceptance (exits nonzero below the
-# 5x serving bar or on any budget violation)
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.serve_bench artifacts/BENCH_serve.json
-
-# measured kernel autotune smoke: roofline-pruned config search at the
-# smoke shapes, winners persisted to artifacts/tuning/, then the reload
-# acceptance — tuned config loaded back from disk, warm second run with
-# no retrace, config label visible in dispatch.stats() (exits nonzero on
-# any parity or round-trip failure)
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.kernel_bench artifacts/BENCH_kernel.json
-
-# steady-state throughput gate vs the committed baselines (>30% fails)
-python scripts/bench_gate.py artifacts benchmarks/baselines

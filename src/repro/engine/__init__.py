@@ -228,45 +228,19 @@ first.  The contract:
   when their candidate grids agree, because the per-lane table rows are
   batched operands too.
 
-``launch.fleet_serve`` drives the service under bursty open-loop load;
-``benchmarks/serve_bench.py`` gates the coalescing speedup.
+``launch.fleet_serve`` drives the service under bursty open-loop load.
 
-Kernel configs and measured autotuning
-======================================
+Kernel tiling and speed
+=======================
 
-The inner kernels' tiling knobs are explicit configs, not module
-constants: :mod:`repro.kernels.autotune` defines a hashable
-``KernelConfig`` per kernel (``voltage_inject`` / ``sweep_solve`` — the
-Pallas row/lane block sizes plus the oracle's batch-chunk and scan-unroll
-knobs) and a roofline-pruned measured search (``autotune.tune``) whose
-winners persist to ``artifacts/tuning/TUNE_<backend>_<device_kind>.json``
-keyed by (kernel, pow2 shape bucket).  The engine contract:
-
-- **Defaults are bit-exact:** with tuning disabled (the default and the
-  test-suite state), every path runs ``autotune.DEFAULTS`` — exactly the
-  pre-tuning module constants.  Enabling tuning is explicit:
-  ``autotune.enable(path)`` or ``REPRO_KERNEL_TUNING=1`` (or ``=<path>``).
-- **Configs ride the dispatch statics:** the dispatched entry points
-  (``solve._grid_sim_dispatched``, ``controller.run_flat``, the service's
-  fleet megabatches, ``test1``'s injection plane) resolve
-  ``autotune.active_config(kernel, flat_shape)`` per call and thread the
-  config into both the AOT ``statics_key`` (a config changes the traced
-  program, so it must key the executable cache — and via the persistent
-  ``artifacts/jax_cache`` the tuned executable survives restarts) and the
-  stats row (``dispatch.stats()`` reports ``config_last`` plus every
-  distinct ``kernel_configs`` label the entry compiled against).
-- **The parity reference stays pinned:** ``dispatch="direct"`` and direct
-  kernel calls never consult the tuning table, so every scalar-parity
-  test above compares against today's bit-exact behavior regardless of
-  tuning state.  The tuner itself enforces parity before eligibility —
-  a candidate config must match the default's output (bit-exact for the
-  integer ``voltage_inject``, <=1e-6 for the float ``sweep_solve``) or it
-  is recorded ineligible and cannot win.
-
-``benchmarks/kernel_bench.py`` runs the search (full shapes under
-``benchmarks/run.py kernel``, smoke shapes + the reload round-trip under
-``scripts/check.sh``) and ``scripts/bench_gate.py`` gates the measured
-tuned-vs-default speedup.
+Each kernel takes its tiling from its own shape rule:
+``sweep_solve.kernel.block_rows`` (at most ``ROW_BLOCK`` rows of 128
+samples a grid step, fewer for a small batch) and ``voltage_inject``'s
+``ROW_BLOCK`` x ``WORD_BLOCK`` tile, with non-tile-aligned planes padded
+and sliced back.  The block sizes stay plain keywords at the kernel
+layer, where the tests run several grid steps at small sizes; the engine
+never passes them.  Speed is judged on the chip by the benchmark in
+``bench/`` (cells listed in ``BENCHMARK.json``), not on the CPU.
 
 Scalar-wrapper compatibility
 ============================

@@ -70,7 +70,7 @@ def _predict(coef_lo, coef_hi, lat, mpki, stall):
 
 def _controller_scan_fn(feats, phases, coef_lo, coef_hi, target, cand_v,
                         lat_feat, cand_t, cand_valid, model_coeffs=None,
-                        impl: str = "reference", solve_cfg=None):
+                        impl: str = "reference"):
     """The interval scan over W flat lanes.
 
     ``cand_t`` holds per-element [W, K] (tRCD, tRP, tRAS) candidate tables
@@ -89,9 +89,6 @@ def _controller_scan_fn(feats, phases, coef_lo, coef_hi, target, cand_v,
     per-component DRAM energy is accumulated through the scan carry.
     Selections are independent of the model: Algorithm 1 reads only the
     loss predictions, never the energy accumulators.
-
-    ``solve_cfg``: optional (hashable) ``autotune.KernelConfig`` for the
-    inner fixed-point solves (None = default, today's behavior).
     """
     w, c = feats["mpki"].shape
     nominal = {k: jnp.broadcast_to(v, (w,))
@@ -102,8 +99,7 @@ def _controller_scan_fn(feats, phases, coef_lo, coef_hi, target, cand_v,
         return sweep_ops.solve(
             mpki_t, feats["ipc_base"], feats["mlp"], feats["row_hit"],
             feats["eff_banks"], feats["write_mult"], t_rcd, t_rp, t_ras,
-            nominal["transfer_ns"], nominal["peak_bw_gbps"], impl=impl,
-            config=solve_cfg)
+            nominal["transfer_ns"], nominal["peak_bw_gbps"], impl=impl)
 
     def metrics(out, alone, points):
         ipc = out["ipc"]
@@ -119,8 +115,7 @@ def _controller_scan_fn(feats, phases, coef_lo, coef_hi, target, cand_v,
     def step(carry, f):
         v_idx, sums = carry
         mpki_t = feats["mpki"] * f[:, None]
-        alone = engine_solve.alone_solve(feats, mpki=mpki_t, impl=impl,
-                                         solve_cfg=solve_cfg)
+        alone = engine_solve.alone_solve(feats, mpki=mpki_t, impl=impl)
         base = shared_solve(mpki_t, nominal["t_rcd"], nominal["t_rp"],
                             nominal["t_ras"])
         pt = shared_solve(mpki_t, gather(cand_t["t_rcd"], v_idx),
@@ -189,11 +184,10 @@ def _controller_scan_fn(feats, phases, coef_lo, coef_hi, target, cand_v,
     }
 
 
-_controller_scan = jax.jit(_controller_scan_fn,
-                           static_argnames=("impl", "solve_cfg"))
+_controller_scan = jax.jit(_controller_scan_fn, static_argnames=("impl",))
 
 
-def _controller_flat_fn(*args, impl: str, solve_cfg=None):
+def _controller_flat_fn(*args, impl: str):
     """``_controller_scan_fn`` in :func:`repro.engine.dispatch.dispatch_flat`
     form: every batched operand leads with the flat W (or W x D) axis —
     the [T, W] phase schedule rides transposed as [W, T] — followed by the
@@ -211,7 +205,7 @@ def _controller_flat_fn(*args, impl: str, solve_cfg=None):
     cand_t = {"t_rcd": t_rcd, "t_rp": t_rp, "t_ras": t_ras}
     return _controller_scan_fn(feats, phases_nt.T, coef_lo, coef_hi, target,
                                cand_v, lat_feat, cand_t, cand_valid,
-                               model_coeffs, impl=impl, solve_cfg=solve_cfg)
+                               model_coeffs, impl=impl)
 
 
 def element_cost(n_intervals: int) -> int:
@@ -307,19 +301,14 @@ def run_flat(entry: str, feats: dict, phases, coef_lo, coef_hi,
              "t_ras": jnp.asarray(batched[13])},
             jnp.asarray(batched[14]), jnp.asarray(batched[15]), impl=impl)
     elif dispatch in ("auto", "bucketed", "chunked"):
-        from repro.kernels import autotune
-        solve_cfg = autotune.active_config(
-            "sweep_solve", (batched[0].shape[0], batched[0].shape[1]))
         cfg = None if max_elements_resident is None else \
             dispatch_lib.DispatchConfig(
                 max_elements_resident=int(max_elements_resident))
         out = dispatch_lib.dispatch_flat(
-            entry, functools.partial(_controller_flat_fn, impl=impl,
-                                     solve_cfg=solve_cfg),
-            batched, replicated,
-            statics_key=(impl, solve_cfg.key()), mesh=mesh, mode=dispatch,
-            element_cost=element_cost(n_intervals), config=cfg,
-            config_label=solve_cfg.key())
+            entry, functools.partial(_controller_flat_fn, impl=impl),
+            batched, replicated, statics_key=(impl,), mesh=mesh,
+            mode=dispatch, element_cost=element_cost(n_intervals),
+            config=cfg)
     else:
         raise ValueError(f"unknown dispatch {dispatch!r}")
     out = {k: np.asarray(v) for k, v in out.items()}

@@ -210,15 +210,9 @@ def stats(entry: str | None = None) -> dict:
     (device-to-host copies and the slice to N), and on row ``tables`` one
     stage per reliability policy.  ``wire_bytes_total`` counts the output
     bytes fetched as float64 words (:func:`dispatch_flat`).  :func:`spans`
-    holds the spans themselves.
-    Entries whose callers pass ``config_label`` (the engine paths that
-    resolve an ``autotune.KernelConfig`` per dispatch) additionally report
-    ``config_last`` (the label of the most recent call) and
-    ``kernel_configs`` (every distinct label this entry has compiled
-    against — the label also rides the caller's ``statics_key``, so each
-    listed config corresponds to its own cached executable).  Gauges
-    attached via :func:`record_gauge` (e.g. the serving front-end's
-    queue depth) appear alongside the counters."""
+    holds the spans themselves.  Gauges attached via :func:`record_gauge`
+    (e.g. the serving front-end's queue depth) appear alongside the
+    counters."""
     with _LOCK:
         if entry is not None:
             return dict(_stats_entry(entry))
@@ -315,8 +309,7 @@ def clear_cache() -> None:
 
 
 def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
-             donate: bool = False, resident: int | None = None,
-             config_label: str | None = None):
+             donate: bool = False, resident: int | None = None):
     """Run ``fn(*args)`` through the AOT executable cache.
 
     ``fn`` must be jit-able with every static already closed over;
@@ -324,11 +317,6 @@ def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
     differs at equal arg shapes.  The cache key is (entry, statics_key,
     arg treedef, every leaf's shape/dtype, x64 flag, donation) — exactly
     the trace key, so ``stats(entry)["compiles"]`` counts real retraces.
-
-    ``config_label`` is observability only: callers that resolve a tuned
-    kernel config per dispatch pass its label here so ``stats(entry)``
-    reports which config each executable compiled against (the config must
-    *also* ride ``statics_key`` — it changes the traced program).
     """
     flat, treedef = jax.tree.flatten(args)
     key = (entry, tuple(statics_key), treedef,
@@ -339,11 +327,6 @@ def aot_call(entry: str, fn, args: tuple, *, statics_key=(),
         s["calls"] += 1
         if resident:
             s["max_resident"] = max(s["max_resident"], int(resident))
-        if config_label is not None:
-            s["config_last"] = config_label
-            seen = s.setdefault("kernel_configs", ())
-            if config_label not in seen:
-                s["kernel_configs"] = seen + (config_label,)
         compiled = _EXECUTABLES.get(key)
         key_lock = _KEY_LOCKS.setdefault(key, threading.Lock())
     if compiled is None:
@@ -426,8 +409,7 @@ def _chunk_fn(kernel, n_batched: int):
 def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
                   statics_key=(), mesh=None, element_cost: int = 1,
                   config: DispatchConfig | None = None,
-                  mode: str = "auto",
-                  config_label: str | None = None) -> dict:
+                  mode: str = "auto") -> dict:
     """Dispatch one flat-batch kernel call shape-stably.
 
     ``kernel(*batched, *replicated, valid)`` maps the leading (flat batch)
@@ -458,8 +440,6 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
     resident bucket).  Float64 outputs cross as ``uint32`` words
     converted inside the executable; ``fetch`` carries their bytes as
     attr ``wire_bytes``.
-    ``config_label`` is forwarded to :func:`aot_call` for stats reporting
-    of the caller's resolved kernel-tuning config (see that docstring).
     """
     cfg = config or DispatchConfig()
     mesh = mesh_lib.make_batch_mesh() if mesh is None else mesh
@@ -498,8 +478,7 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
         if n_devices > 1:
             kernel = lane_sharded(kernel, mesh, len(batched), len(rep), 0)
         out = aot_call(entry, kernel, args[:-1] + rep + args[-1:],
-                       statics_key=statics_key, resident=bucket,
-                       config_label=config_label)
+                       statics_key=statics_key, resident=bucket)
         with _fetch_span(entry, out):
             return {k: v[:n] for k, v in _host_outputs(out).items()}
 
@@ -527,7 +506,7 @@ def dispatch_flat(entry: str, kernel, batched, replicated=(), *,
         fn = lane_sharded(fn, mesh, len(stacked), len(rep), 1)
     out = aot_call(entry + "/chunked", fn,
                    stacked + (valid,) + rep, statics_key=statics_key,
-                   donate=True, resident=chunk, config_label=config_label)
+                   donate=True, resident=chunk)
     with _fetch_span(entry, out):
         return {key: v.reshape((k * chunk,) + v.shape[2:])[:n]
                 for key, v in _host_outputs(out).items()}

@@ -62,8 +62,9 @@ compatible within an entry point, indifferent to batch composition.
   per-lane operands, never statics.
 
 ``run_request`` serves one request synchronously through the same lowering
-(one dispatch per request) — the request-at-a-time baseline the coalescing
-path is benchmarked against (``benchmarks/serve_bench.py``).
+(one dispatch per request): the request-at-a-time baseline, which the chip
+benchmark's served job also uses to warm its executables
+(``bench/jobs/serve.py``).
 
 Threading note: dispatches run on one worker thread, which serializes
 the service's own dispatches.  The float64 entry points enter
@@ -196,11 +197,7 @@ class ServiceConfig:
 # --------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class _GroupSpec:
-    """Everything a flush needs that is shared by the whole group.
-
-    ``config_label`` carries the resolved kernel-tuning config label (when
-    the lowering consulted ``autotune.active_config``) so the dispatch
-    stats row reports it; the config itself rides ``statics_key``."""
+    """Everything a flush needs that is shared by the whole group."""
 
     entry: str
     kernel: object
@@ -208,7 +205,6 @@ class _GroupSpec:
     statics_key: tuple
     element_cost: int
     x64: bool
-    config_label: str | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -539,8 +535,7 @@ class EngineService:
             return dispatch_lib.dispatch_flat(
                 spec.entry, spec.kernel, batched, spec.replicated,
                 statics_key=spec.statics_key, mesh=self._mesh,
-                element_cost=spec.element_cost, config=cfg, mode=mode,
-                config_label=spec.config_label)
+                element_cost=spec.element_cost, config=cfg, mode=mode)
 
         if spec.x64:
             with jax.enable_x64(True):
@@ -701,20 +696,14 @@ class EngineService:
         c = wb.mpki.shape[1]
         coef_lo32 = np.asarray(model.coef_low, np.float32)
         coef_hi32 = np.asarray(model.coef_high, np.float32)
-        # the tuned solve config participates in the coalescing key: lanes
-        # compiled against different configs must not share an executable
-        from repro.kernels import autotune
-        solve_cfg = autotune.active_config("sweep_solve", (w * d, c))
         key = ("fleet", impl, t, c, float(req.target_loss_pct),
-               coef_lo32.tobytes(), coef_hi32.tobytes(), cand_bytes,
-               solve_cfg.key())
+               coef_lo32.tobytes(), coef_hi32.tobytes(), cand_bytes)
         spec = _GroupSpec(
             "fleet", functools.partial(controller._controller_flat_fn,
-                                       impl=impl, solve_cfg=solve_cfg),
+                                       impl=impl),
             (coef_lo32, coef_hi32, np.float32(req.target_loss_pct),
              np.asarray(cand_v, np.float32)),
-            (impl, solve_cfg.key()), controller.element_cost(t), False,
-            config_label=solve_cfg.key())
+            (impl,), controller.element_cost(t), False)
 
         def resolve():
             st = self._stacks.get(stack_name)

@@ -53,12 +53,10 @@ def _pg_points(pg: PointGrid) -> dict:
 NOMINAL_POINT = _pg_points(PointGrid.nominal())
 
 
-def alone_solve(feats: dict, mpki=None, impl: str = "reference",
-                solve_cfg=None) -> jnp.ndarray:
+def alone_solve(feats: dict, mpki=None,
+                impl: str = "reference") -> jnp.ndarray:
     """Single-core IPC of every (workload, core) at the nominal point
-    -> [W, C].  ``mpki`` overrides the batch's (for phased workloads).
-    ``solve_cfg``: optional ``autotune.KernelConfig`` for the inner solve
-    (None = default, today's behavior)."""
+    -> [W, C].  ``mpki`` overrides the batch's (for phased workloads)."""
     mpki = feats["mpki"] if mpki is None else mpki
     w, c = mpki.shape
     flat = lambda x: x.reshape(w * c, 1)
@@ -69,7 +67,7 @@ def alone_solve(feats: dict, mpki=None, impl: str = "reference",
         scal(feats["alone_row_hit"]), scal(feats["alone_eff_banks"]),
         scal(feats["alone_write_mult"]),
         n["t_rcd"], n["t_rp"], n["t_ras"], n["transfer_ns"],
-        n["peak_bw_gbps"], impl=impl, config=solve_cfg)
+        n["peak_bw_gbps"], impl=impl)
     return out["ipc"].reshape(w, c)
 
 
@@ -108,12 +106,11 @@ def _power_energy(points: dict, acts, reads, total_ipc, runtime_s,
 
 
 def _grid_sim_fn(feats: dict, points: dict, impl: str = "reference",
-                 coeffs: tuple | None = None, solve_cfg=None) -> dict:
+                 coeffs: tuple | None = None) -> dict:
     """The full [W, P] grid simulation; returns a dict of jnp arrays.
     ``coeffs``: optional device-model coefficient tuple (hashable, rides as
     a jit-static argument — one model per grid; per-lane mixes go through
-    the controller/fleet path).  ``solve_cfg``: optional (hashable)
-    ``autotune.KernelConfig`` for the inner fixed-point solves."""
+    the controller/fleet path)."""
     w, c = feats["mpki"].shape
     p = points["t_rcd"].shape[0]
     per_core = lambda x: jnp.broadcast_to(x[:, None, :], (w, p, c)) \
@@ -127,10 +124,10 @@ def _grid_sim_fn(feats: dict, points: dict, impl: str = "reference",
         per_wl(feats["eff_banks"]), per_wl(feats["write_mult"]),
         per_pt(points["t_rcd"]), per_pt(points["t_rp"]),
         per_pt(points["t_ras"]), per_pt(points["transfer_ns"]),
-        per_pt(points["peak_bw_gbps"]), impl=impl, config=solve_cfg)
+        per_pt(points["peak_bw_gbps"]), impl=impl)
 
     ipc = out["ipc"].reshape(w, p, c)
-    alone = alone_solve(feats, impl=impl, solve_cfg=solve_cfg)  # [W, C]
+    alone = alone_solve(feats, impl=impl)                 # [W, C]
     ws = jnp.sum(ipc / alone[:, None, :], axis=-1)
     runtime_s = jnp.max(INSTR_PER_CORE / (ipc * CPU_FREQ_HZ), axis=-1)
     total_ipc = jnp.sum(ipc, axis=-1)
@@ -147,8 +144,7 @@ def _grid_sim_fn(feats: dict, points: dict, impl: str = "reference",
             "bus_utilization": out["utilization"].reshape(w, p), **pe}
 
 
-_grid_sim = jax.jit(_grid_sim_fn,
-                    static_argnames=("impl", "coeffs", "solve_cfg"))
+_grid_sim = jax.jit(_grid_sim_fn, static_argnames=("impl", "coeffs"))
 
 
 def _grid_sim_dispatched(feats: dict, points: dict, impl: str,
@@ -156,30 +152,20 @@ def _grid_sim_dispatched(feats: dict, points: dict, impl: str,
     """``_grid_sim`` through the shape-stable dispatch layer: the W and P
     axes are padded up to canonical buckets so any workload x point grid
     hits a warm AOT executable (the kernel reduces only over the core axis,
-    so padded lanes are dead rows sliced off here — no mask needed).
-
-    This dispatched path resolves the tuned solve config for the padded
-    flat batch (``autotune.active_config`` — the default config unless
-    tuning is enabled); the config rides the AOT ``statics_key`` (it
-    changes the traced program) and its label lands on the stats row."""
-    from repro.kernels import autotune
+    so padded lanes are dead rows sliced off here — no mask needed)."""
     w, p = feats["mpki"].shape[0], points["t_rcd"].shape[0]
     ladder = dispatch_lib.bucket_ladder(1)
     bw = dispatch_lib.pick_bucket(w, ladder) or w
     bp = dispatch_lib.pick_bucket(p, ladder) or p
-    cfg = autotune.active_config("sweep_solve",
-                                 (bw * bp, feats["mpki"].shape[1]))
     pf = {k: jnp.asarray(dispatch_lib.pad_axis(a, bw))
           for k, a in feats.items()}
     pp = {k: jnp.asarray(dispatch_lib.pad_axis(a, bp))
           for k, a in points.items()}
     r = dispatch_lib.aot_call("grid_sim",
                               functools.partial(_grid_sim_fn, impl=impl,
-                                                coeffs=coeffs,
-                                                solve_cfg=cfg),
-                              (pf, pp),
-                              statics_key=(impl, coeffs, cfg.key()),
-                              resident=bw * bp, config_label=cfg.key())
+                                                coeffs=coeffs),
+                              (pf, pp), statics_key=(impl, coeffs),
+                              resident=bw * bp)
     return {k: (a[:w] if k == "alone_ipc" else a[:w, :p])
             for k, a in r.items()}
 

@@ -150,7 +150,7 @@ def _bank_key_data(indices, rounds: int, seed: int, banks: int) -> np.ndarray:
 # The flat-batch kernel
 # --------------------------------------------------------------------------
 def _test1_flat_fn(p_word, key_data, p_idx, patterns, valid, *, banks, rows,
-                   words, nplanes, inject_impl, inject_cfg=None):
+                   words, nplanes, inject_impl):
     """One Test-1 evaluation of the flat N = D*V*P*R batch.
 
     ``p_word`` float32 [N, banks, rows]; ``key_data`` uint32 [N, banks, 2, 2];
@@ -160,8 +160,7 @@ def _test1_flat_fn(p_word, key_data, p_idx, patterns, valid, *, banks, rows,
     the carried key data — under chunked dispatch that means one chunk's
     planes at a time — and the corruption runs as a single
     ``voltage_inject`` dispatch over the flattened [N*banks*rows, words]
-    plane.  ``inject_cfg``: optional (hashable) ``autotune.KernelConfig``
-    for that dispatch (None = default, today's behavior).
+    plane.
     """
     n = p_word.shape[0]
     # write data into even rows, ~data into odd rows (Test 1 lines 4-5)
@@ -184,7 +183,7 @@ def _test1_flat_fn(p_word, key_data, p_idx, patterns, valid, *, banks, rows,
         p_word.reshape(plane_rows),
         rand_word.reshape(plane_rows, words),
         jnp.moveaxis(rand_planes, 1, 0).reshape(nplanes, plane_rows, words),
-        impl=inject_impl, config=inject_cfg)
+        impl=inject_impl)
 
     flips = jax.lax.population_count(got ^ data.reshape(plane_rows, words))
     flips = flips.reshape(n, banks, rows, words).astype(jnp.int32)
@@ -200,7 +199,7 @@ def _test1_flat_fn(p_word, key_data, p_idx, patterns, valid, *, banks, rows,
 
 _test1_flat = jax.jit(_test1_flat_fn,
                       static_argnames=("banks", "rows", "words", "nplanes",
-                                       "inject_impl", "inject_cfg"))
+                                       "inject_impl"))
 
 
 def _dispatch_test1_plane(entry, inputs, patterns, statics, mesh,
@@ -241,18 +240,11 @@ def _dispatch_test1_plane(entry, inputs, patterns, statics, mesh,
             max_elements_resident=int(max_elements_resident))
     banks, rows, words, nplanes = (statics["banks"], statics["rows"],
                                    statics["words"], statics["nplanes"])
-    # tuned inject config for the flattened [N*banks*rows, words] plane
-    # (the default config unless tuning is enabled); it becomes a static
-    # of the traced program, so it rides the statics dict / statics_key
-    from repro.kernels import autotune
-    inject_cfg = autotune.active_config(
-        "voltage_inject", (len(inputs[0]) * banks * rows, words))
-    statics = dict(statics, inject_cfg=inject_cfg)
     out = dispatch_lib.dispatch_flat(
         entry, functools.partial(_test1_flat_fn, **statics),
         inputs, (patterns,), statics_key=tuple(sorted(statics.items())),
         mesh=mesh, element_cost=(nplanes + 4) * banks * rows * words,
-        mode=dispatch_mode, config=cfg, config_label=inject_cfg.key())
+        mode=dispatch_mode, config=cfg)
     return {k: np.asarray(a) for k, a in out.items()}
 
 

@@ -1,7 +1,15 @@
-"""One benchmark per paper table/figure.  Each function returns a list of
-CSV rows ``(name, value, derived)`` and prints a compact table; run.py
-aggregates all of them (plus wall-time per call)."""
+"""The paper's Table 3 and Figures 4-19, computed through the engine.
+
+  python -m repro.figures            # every figure
+  python -m repro.figures fig14      # names containing "fig14"
+
+Each function returns CSV rows ``(name, value, derived)``; ``main`` prints
+them under a ``name,value,derived`` header and exits nonzero if any
+function raised.
+"""
 from __future__ import annotations
+
+import sys
 
 import numpy as np
 
@@ -199,7 +207,6 @@ def fig14_15_voltron_vs_memdvfs():
             f"loss={np.mean([r.perf_loss_pct for r in dr]):.1f}%",
             f"sysE={np.mean([r.system_energy_savings_pct for r in dr]):.1f}% "
             f"(paper: ~0 for mem)"))
-        cpu_inc = np.mean([r.perf_loss_pct for r in vr])  # proxy
         rows.append((f"fig15/{label}",
                      f"dram_energy_savings={np.mean([r.dram_energy_savings_pct for r in vr]):.1f}%",
                      ""))
@@ -279,3 +286,25 @@ ALL = [
     fig14_15_voltron_vs_memdvfs, fig16_bank_locality, fig17_heterogeneous,
     fig18_target_sweep, fig19_interval_sweep,
 ]
+
+
+def main() -> int:
+    pattern = sys.argv[1] if len(sys.argv) > 1 else ""
+    print("name,value,derived")
+    failures = 0
+    for fn in ALL:
+        if pattern not in fn.__name__:
+            continue
+        try:
+            rows = fn()
+        except Exception as e:  # noqa: BLE001 — report and continue
+            failures += 1
+            print(f"{fn.__name__},ERROR,{type(e).__name__}: {e}")
+            continue
+        for name, value, derived in rows:
+            print(f'{name},"{value}","{derived}"')
+    return 1 if failures else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -41,8 +41,7 @@ LINES_PER_ROW = 128              # 8 KB row = 128 x 64 B lines (Section 2.1)
 # (DDR), in ns — and the DIMM's peak bandwidth at the rated transfer
 # speed across the 2-channel system (Table 2): 2 * 1600 MT/s * 8 B/beat.
 # These parameterize the benign pad rows of the sweep-solve feature
-# packing and the benchmark/tuner synthetic inputs (one source of truth;
-# they used to be the magic numbers 5.0 / 25.6).
+# packing (they used to be the magic numbers 5.0 / 25.6).
 LINE_TRANSFER_NS = BEATS_PER_LINE * DDR3L_CLK_NS / 2          # 5.0 ns
 PEAK_BW_GBPS = 2 * DDR3L_DATA_RATE * (BEAT_BITS // 8) / 1000.0  # 25.6 GB/s
 
@@ -113,12 +112,3 @@ def device_spec(device_kind: str) -> TpuSpec:
     except KeyError:
         raise KeyError(f"no published peaks for device kind {device_kind!r};"
                        " add it to hw.DEVICE_PEAKS with its source") from None
-
-
-# Rough development-host CPU spec for the kernel autotuner's roofline
-# pruning when no accelerator is attached (~a few AVX cores + dual-channel
-# DDR4).  Only the *relative ordering* of candidate lower bounds matters —
-# the tuner measures every surviving candidate, so absolute error here
-# costs measurement time, never correctness.
-HOST_CPU = TpuSpec(peak_flops=1.0e11, hbm_bw=2.0e10, ici_bw=1.0e9,
-                   hbm_bytes=16 * 1024**3, vmem_bytes=32 * 1024**2)

@@ -103,20 +103,16 @@ def _solve_kernel(c: int, iters: int, t_cl: float, feat_ref, out_ref):
 
 @functools.partial(jax.jit,
                    static_argnames=("n_cores", "iters", "t_cl", "interpret",
-                                    "row_block", "lanes"))
+                                    "row_block"))
 def solve_pallas(feat, n_cores: int, iters: int = 25,
                  t_cl: float = hw.T_CL_STD, *, interpret: bool = False,
-                 row_block: int = ROW_BLOCK, lanes: int = LANES):
+                 row_block: int = ROW_BLOCK):
     """Run the lane-dense fixed-point solve.  ``feat``: float32[3C + 8, R,
     128] with R a multiple of ``row_block``, itself a multiple of the
-    8-row sublane tile (``ops.solve`` picks it with ``block_rows``; the
-    autotuner passes measured alternatives).  Returns float32[C + 2, R,
-    128] (see the planes above)."""
-    f, rows, got_lanes = feat.shape
-    if lanes != LANES:
-        raise ValueError(f"lane_block {lanes} != {LANES}: this kernel lays "
-                         f"128 samples along the lanes")
-    if (f != 3 * n_cores + N_SCALARS or got_lanes != LANES
+    8-row sublane tile (``ops.solve`` picks it with ``block_rows``).
+    Returns float32[C + 2, R, 128] (see the planes above)."""
+    f, rows, lanes = feat.shape
+    if (f != 3 * n_cores + N_SCALARS or lanes != LANES
             or row_block % SUBLANES or rows % row_block):
         raise ValueError(f"feat shape {feat.shape} must be "
                          f"[{3 * n_cores + N_SCALARS}, k*{row_block}, "

@@ -40,8 +40,7 @@ DEFAULT_ITERS = 25
 
 def solve_ref(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
               t_rcd, t_rp, t_ras, transfer_ns, peak_bw_gbps,
-              t_cl: float = hw.T_CL_STD, iters: int = DEFAULT_ITERS,
-              unroll: int = 1):
+              t_cl: float = hw.T_CL_STD, iters: int = DEFAULT_ITERS):
     n_cores = mpki.shape[-1]
     miss = 1.0 - row_hit
     t_rc = t_ras + t_rp
@@ -85,14 +84,12 @@ def solve_ref(mpki, ipc_base, mlp, row_hit, eff_banks, write_mult,
         new_ipc = 0.5 * ipc + 0.5 / cpi                  # damped fixed point
         return (new_ipc, loaded, util), None
 
-    # ``unroll`` is an autotuner knob (repro.kernels.autotune): it changes
-    # only how XLA lowers the loop, never the step sequence.  While the
-    # loop keeps two or more trips the result is bit-identical to
-    # unroll=1; at one trip XLA inlines the loop, fuses across steps and
-    # the float32 results can move by a few ulps.
+    # The scan stays rolled.  Unrolling changes only how XLA lowers the
+    # loop, never the step sequence, and is bit-identical while the loop
+    # keeps two or more trips; at one trip XLA inlines the loop, fuses
+    # across steps and the float32 results can move by a few ulps.
     init = (ipc_base, jnp.zeros_like(svc), jnp.zeros_like(svc))
-    (ipc, loaded, util), _ = jax.lax.scan(step, init, None, length=iters,
-                                          unroll=max(1, int(unroll)))
+    (ipc, loaded, util), _ = jax.lax.scan(step, init, None, length=iters)
     return finalize(ipc, loaded, util, mpki, ipc_base, row_hit)
 
 

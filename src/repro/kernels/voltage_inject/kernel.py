@@ -10,9 +10,9 @@ integer ops (compare / AND / XOR), which map onto the TPU VPU lanes.
 Tiling: rows x words blocks of (8, 1024) uint32 = 32 KiB per operand block,
 five operands resident -> ~160 KiB of VMEM per grid step, well inside the
 ~16 MiB VMEM budget while keeping the lane dimension (1024 words = 8 x 128
-lanes) MXU/VPU aligned.  ``ROW_BLOCK`` / ``WORD_BLOCK`` are the *default*
-tile; the autotuner (``repro.kernels.autotune``) passes measured
-alternatives through the ``row_block`` / ``word_block`` statics.
+lanes) MXU/VPU aligned.  ``ROW_BLOCK`` / ``WORD_BLOCK`` are the default
+tile; the ``row_block`` / ``word_block`` statics take another (the tests
+run several grid steps at small sizes that way).
 """
 from __future__ import annotations
 

@@ -22,11 +22,10 @@ from repro.kernels.voltage_inject import ref as _ref
 
 
 def _inject_padded(data, row_prob, rand_word, rand_planes, *, interpret,
-                   row_block=None, word_block=None):
+                   row_block: int = _kernel.ROW_BLOCK,
+                   word_block: int = _kernel.WORD_BLOCK):
     """Pad every operand's plane up to the kernel tile grid, run the Pallas
     kernel, slice the result back to the original shape."""
-    row_block = row_block or _kernel.ROW_BLOCK
-    word_block = word_block or _kernel.WORD_BLOCK
     r, w = data.shape
     pad_r = (-r) % row_block
     pad_w = (-w) % word_block
@@ -44,58 +43,13 @@ def _inject_padded(data, row_prob, rand_word, rand_planes, *, interpret,
     return out
 
 
-def _inject_ref_chunked(data, row_prob, rand_word, rand_planes, *, chunk):
-    """Oracle with a tunable row-chunk: run ``inject_ref`` over
-    ``chunk``-row slabs through ``lax.map`` instead of one whole-plane
-    expression.  The math is elementwise, so padding rows and slicing them
-    back keeps every chunk size bit-identical to the default oracle; what
-    changes is XLA's fusion/working-set shape — which is exactly the knob
-    the autotuner measures on CPU."""
-    r, w = data.shape
-    p = rand_planes.shape[0]
-    chunk = max(1, int(chunk))
-    pad_r = (-r) % chunk
-    if pad_r:
-        data = jnp.pad(data, ((0, pad_r), (0, 0)))
-        rand_word = jnp.pad(rand_word, ((0, pad_r), (0, 0)))
-        rand_planes = jnp.pad(rand_planes, ((0, 0), (0, pad_r), (0, 0)))
-        row_prob = jnp.pad(row_prob, (0, pad_r))
-    k = (r + pad_r) // chunk
-    planes_r = jnp.moveaxis(rand_planes, 0, 1)          # [r, p, w]
-    xs = (data.reshape(k, chunk, w), row_prob.reshape(k, chunk),
-          rand_word.reshape(k, chunk, w), planes_r.reshape(k, chunk, p, w))
-    out = jax.lax.map(
-        lambda s: _ref.inject_ref(s[0], s[1], s[2],
-                                  jnp.moveaxis(s[3], 1, 0)), xs)
-    out = out.reshape(k * chunk, w)
-    return out[:r] if pad_r else out
-
-
-def inject(data, row_prob, rand_word, rand_planes, impl: str = "auto",
-           config=None):
-    """Flip bits in ``data`` per the voltage-error model.  See ref.py.
-
-    ``config`` is an optional ``autotune.KernelConfig``: its blocks retile
-    the Pallas paths and a nonzero ``oracle_chunk`` chunks the reference
-    path.  ``None`` (and the default config) reproduce the historical
-    behavior bit-for-bit on every path.
-    """
+def inject(data, row_prob, rand_word, rand_planes, impl: str = "auto"):
+    """Flip bits in ``data`` per the voltage-error model.  See ref.py."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
-        if config is not None and config.oracle_chunk:
-            return jax.jit(_inject_ref_chunked, static_argnames=("chunk",))(
-                data, row_prob, rand_word, rand_planes,
-                chunk=config.oracle_chunk)
         return jax.jit(_ref.inject_ref)(data, row_prob, rand_word, rand_planes)
-    blocks = {}
-    if config is not None:
-        blocks = {"row_block": config.row_block,
-                  "word_block": config.lane_block}
-    if impl == "pallas":
-        return _inject_padded(data, row_prob, rand_word, rand_planes,
-                              interpret=False, **blocks)
-    if impl == "pallas_interpret":
-        return _inject_padded(data, row_prob, rand_word, rand_planes,
-                              interpret=True, **blocks)
-    raise ValueError(f"unknown impl {impl!r}")
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown impl {impl!r}")
+    return _inject_padded(data, row_prob, rand_word, rand_planes,
+                          interpret=(impl == "pallas_interpret"))

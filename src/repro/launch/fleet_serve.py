@@ -14,9 +14,8 @@ is charged to the service, not hidden in the generator.  With ``--rate 0``
 request-at-a-time baseline, so the run demonstrates the coalescing
 headroom directly.
 
-``benchmarks/serve_bench.py`` imports the pieces (``default_service``,
-``request_mix``, ``open_loop``, ``serial_loop``) to produce the gated
-``BENCH_serve.json`` artifact.
+The chip benchmark's served job runs a copy of this open loop and request
+mix (``bench/loadgen.py``).
 """
 from __future__ import annotations
 

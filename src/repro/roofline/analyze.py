@@ -176,37 +176,6 @@ class Roofline:
         return d
 
 
-@dataclasses.dataclass(frozen=True)
-class KernelBound:
-    """Roofline lower bound for a single kernel launch (no collectives) —
-    the pruning term of the kernel autotuner (`repro.kernels.autotune`):
-    a candidate config whose ``bound_s`` already exceeds the incumbent's
-    *measured* time cannot win and is skipped unmeasured."""
-
-    flops: float
-    bytes_accessed: float
-    compute_s: float
-    memory_s: float
-
-    @property
-    def bound_s(self) -> float:
-        return max(self.compute_s, self.memory_s)
-
-    @property
-    def dominant(self) -> str:
-        return "compute" if self.compute_s >= self.memory_s else "memory"
-
-
-def kernel_roofline(flops: float, bytes_accessed: float,
-                    spec: hw.TpuSpec = hw.TPU_V5E) -> KernelBound:
-    """Single-kernel roofline: the same compute/memory terms as
-    :func:`analyze`, minus the collective term (kernels are per-device)."""
-    return KernelBound(
-        flops=float(flops), bytes_accessed=float(bytes_accessed),
-        compute_s=float(flops) / spec.peak_flops,
-        memory_s=float(bytes_accessed) / spec.hbm_bw)
-
-
 def _spec_denom(spec, mesh) -> int:
     denom = 1
     for part in spec:

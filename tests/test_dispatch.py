@@ -288,6 +288,57 @@ def test_emulated_float64_words_exact_off_subnormals():
                                   want[~nan].view(np.uint64))
 
 
+@pytest.fixture(scope="module")
+def fleet_env():
+    from repro.core import perf_model, voltron
+    from repro.memsim import workloads
+    grid = engine.DimmGrid.from_population(("A1", "C2"))
+    return grid, voltron.fleet_tables(grid), \
+        tuple(workloads.homogeneous_workloads()[:2]), perf_model.fit()
+
+
+# each dispatched path, and the HLO module its executable must carry: the
+# chip benchmark's kernel metrics find the device time by these names
+# (bench/metrics/test1_plane_roofline.py, device_ms.controller_scan.py,
+# scan_us_per_lane.job.py)
+MODULE_NAMES = [("test1", "test1", "jit__test1_flat_fn"),
+                ("hammer", "hammer", "jit__test1_flat_fn"),
+                ("test1_chunked", "test1", "jit_fn"),
+                ("fleet", "fleet", "jit__controller_flat_fn"),
+                ("fleet_chunked", "fleet", "jit_fn"),
+                ("service_fleet", "fleet", "jit__controller_flat_fn")]
+
+
+@pytest.mark.parametrize("path,entry,module", MODULE_NAMES)
+def test_executables_carry_the_module_names_the_benchmark_reads(
+        request, path, entry, module):
+    dispatch.clear_cache()
+    kw = dict(rows=8, row_bytes=1024, seed=0)
+    if path.startswith("test1"):
+        mode = "chunked" if path.endswith("chunked") else "bucketed"
+        test1.run_batch(engine.DimmGrid.from_population(("A1",)), [1.2],
+                        dispatch=mode, **kw)
+    elif path == "hammer":
+        test1.run_hammer_batch(engine.DimmGrid.from_population(("A1",)),
+                               [1.2], (100_000,), dispatch="bucketed", **kw)
+    elif path.startswith("fleet"):
+        from repro.core import voltron
+        _, tables, wls, model = request.getfixturevalue("fleet_env")
+        mode = "chunked" if path.endswith("chunked") else "bucketed"
+        voltron.run_fleet(wls, tables=tables, n_intervals=2, model=model,
+                          dispatch=mode)
+    else:
+        from repro.engine import service
+        grid, tables, wls, model = request.getfixturevalue("fleet_env")
+        svc = service.EngineService(grid, tables=tables, workloads=wls,
+                                    model=model)
+        svc.run_request(service.FleetRequest((svc.workload_names[0],),
+                                             ("A1", "C2"), n_intervals=2))
+    exes = dispatch.executables(entry)
+    assert len(exes) == 1, path
+    assert exes[0].as_text().startswith(f"HloModule {module},"), path
+
+
 class TestSystemSweepParity:
     def test_simulate_and_evaluate_bucketed_match_direct(self):
         from repro.core.perf_model import TRAIN_VOLTAGES

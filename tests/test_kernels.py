@@ -1,12 +1,9 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracles."""
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import autotune
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.sweep_solve import kernel as sweep_kernel
@@ -109,14 +106,14 @@ class TestSweepSolveEdges:
         subtraction turns an ipc ulp into a large relative error, so it
         also gets an absolute 1e-6 (ipc's relative tolerance)."""
         args = _solve_args(b, c, seed=b + c)
-        cfg = None
-        if row_block is not None:
-            cfg = dataclasses.replace(autotune.DEFAULTS["sweep_solve"],
-                                      row_block=row_block)
+        ref = sweep_ops.solve(*args, impl="reference")
+        if row_block is None:
+            pal = sweep_ops.solve(*args, impl="pallas_interpret")
+        else:
             assert sweep_kernel.block_rows(b, row_block) == row_block
             assert -(-b // (row_block * 128)) >= 3
-        ref = sweep_ops.solve(*args, impl="reference")
-        pal = sweep_ops.solve(*args, impl="pallas_interpret", config=cfg)
+            pal = sweep_ops._solve_padded(*args, interpret=True,
+                                          row_block=row_block)
         for k in ref:
             assert pal[k].shape == ref[k].shape, k
             np.testing.assert_allclose(
@@ -156,8 +153,6 @@ class TestSweepSolveEdges:
         with pytest.raises(ValueError):        # C = 1 planes for C = 4
             sweep_kernel.solve_pallas(jnp.zeros((11, 8, 128), jnp.float32),
                                       4, row_block=8)
-        with pytest.raises(ValueError):        # a wrong lane_block
-            sweep_kernel.solve_pallas(feat, 4, row_block=8, lanes=256)
 
     def test_block_rows_adapts_to_the_batch(self):
         """A batch under one block takes its own rows of 128 rounded up
@@ -236,6 +231,31 @@ class TestVoltageInjectEdges:
         pal = inject_ops.inject(data, prob, rw, pls, impl="pallas_interpret")
         assert pal.shape == shape
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(pal))
+
+    @pytest.mark.parametrize("rows,words,row_block,word_block", [
+        (7, 1000, 4, 256),         # neither axis tile-aligned: 2 x 4 steps
+        (70, 1200, 8, 512),        # the default rows: 9 x 3 steps
+        (33, 640, 16, 1024),       # words under one block
+        (20, 2048, 4, 512),        # words aligned, rows padded: 5 x 4 steps
+        (1, 1, 8, 1024),           # a single word
+    ])
+    def test_block_tilings_bit_exact(self, rows, words, row_block,
+                                     word_block):
+        """Any (row_block, word_block) tiling of the padded plane is
+        bit-identical to the oracle: the math is integer elementwise, so
+        the tile changes which grid step computes a word, never a bit."""
+        ks = jax.random.split(jax.random.key(rows * 1201 + words), 4)
+        shape = (rows, words)
+        data = jax.random.bits(ks[0], shape, dtype=jnp.uint32)
+        prob = jax.random.uniform(ks[1], (rows,), jnp.float32, 0, 1)
+        rw = jax.random.bits(ks[2], shape, dtype=jnp.uint32)
+        pls = jax.random.bits(ks[3], (2, *shape), dtype=jnp.uint32)
+        ref = inject_ops.inject(data, prob, rw, pls, impl="reference")
+        pal = inject_ops._inject_padded(data, prob, rw, pls, interpret=True,
+                                        row_block=row_block,
+                                        word_block=word_block)
+        assert pal.shape == shape
+        np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref))
 
     def test_inject_rejects_unknown_impl(self):
         data = jnp.zeros((8, 1024), jnp.uint32)

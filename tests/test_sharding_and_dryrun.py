@@ -125,25 +125,23 @@ def test_multidevice_train_and_decode_run():
 
 
 @pytest.mark.slow
-def test_dryrun_production_cell():
+def test_dryrun_production_cell(tmp_path):
     """One real production-mesh (16x16=256 devices) dry-run cell end-to-end
-    via the launcher (compile + roofline extraction)."""
+    via the launcher (compile + roofline extraction).  The artifact goes to
+    a fresh temporary directory, so the run never passes on a cached one
+    and leaves the checkout as it was."""
     env = dict(os.environ)
     # host-device tests run on the forced-CPU backend; probing a (absent)
     # TPU through libtpu first wastes minutes per subprocess
     env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(__file__))
-    import shutil
-    shutil.rmtree(os.path.join(repo, "artifacts/test_dryrun"),
-                  ignore_errors=True)     # never pass on a cached artifact
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "gemma3-1b",
-         "--shape", "decode_32k", "--out", "artifacts/test_dryrun"],
+         "--shape", "decode_32k", "--out", str(tmp_path)],
         capture_output=True, text=True, timeout=600, cwd=repo,
         env={**env, "PYTHONPATH": "src"})
     assert "OK" in out.stdout, out.stderr[-3000:]
-    art = os.path.join(repo, "artifacts/test_dryrun",
-                       "gemma3-1b_decode_32k_256.json")
+    art = os.path.join(tmp_path, "gemma3-1b_decode_32k_256.json")
     with open(art) as f:
         d = json.load(f)
     assert d["status"] == "ok"

@@ -10,7 +10,6 @@ memory, partitioning) fails here.  The two Pallas kernels must appear as a
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
-import dataclasses
 import functools
 import os
 import sys
@@ -30,7 +29,6 @@ from repro.core import perf_model, voltron  # noqa: E402
 from repro.engine import controller, dispatch, population  # noqa: E402
 from repro.engine import solve as engine_solve  # noqa: E402
 from repro.engine import test1  # noqa: E402
-from repro.kernels import autotune  # noqa: E402
 from repro.kernels.sweep_solve import ops as ss_ops  # noqa: E402
 from repro.kernels.voltage_inject import ops as vi_ops  # noqa: E402
 
@@ -107,12 +105,15 @@ def test_sweep_solve_compiles_at_the_fleet_bucket(one_chip, no_cache):
 def test_sweep_solve_compiles_lane_dense(one_chip, no_cache, b, c,
                                          row_block):
     """The lane-dense kernel at the controller scan's shapes, with the
-    default adaptive block and with each block the autotuner searches."""
-    cfg = None if row_block is None else dataclasses.replace(
-        autotune.DEFAULTS["sweep_solve"], row_block=row_block)
+    default adaptive block and with blocks of 8 to 64 rows passed at the
+    kernel layer."""
     per_core = _sds((b, c), jnp.float32, one_chip)
     per_lane = _sds((b,), jnp.float32, one_chip)
-    fn = jax.jit(lambda *a: ss_ops.solve(*a, impl="pallas", config=cfg))
+    if row_block is None:
+        fn = jax.jit(lambda *a: ss_ops.solve(*a, impl="pallas"))
+    else:
+        fn = jax.jit(lambda *a: ss_ops._solve_padded(
+            *a, interpret=False, row_block=row_block))
     compiled = fn.lower(*([per_core] * 3 + [per_lane] * 8)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info["ipc"].shape == (b, c)
@@ -170,8 +171,7 @@ def _stress_operands():
     p_word = test1._word_probs(grid, v, 10.0, 10.0, 20.0, rows)
     kd = test1._bank_key_data([d.index for d in grid.dimms], 1, 0, 8)
     statics = dict(banks=8, rows=rows, words=chip_smoke.ROW_BYTES // 4,
-                   nplanes=chip_smoke.NPLANES, inject_impl="pallas",
-                   inject_cfg=autotune.DEFAULTS["voltage_inject"])
+                   nplanes=chip_smoke.NPLANES, inject_impl="pallas")
     return [p_word.reshape(3, 8, rows), kd.reshape(3, 8, 2, 2),
             np.zeros(3, np.int32)], statics
 
@@ -222,8 +222,7 @@ def test_test1_plane_holds_the_kernel(one_chip, no_cache):
 def test_controller_scan_holds_the_kernel(one_chip, no_cache):
     batched, replicated = _fleet_operands(FLEET_BUCKET)
     kernel = functools.partial(controller._controller_flat_fn,
-                               impl="pallas",
-                               solve_cfg=autotune.DEFAULTS["sweep_solve"])
+                               impl="pallas")
     args, valid, rep = _lane_specs(batched, replicated, lambda nd: one_chip,
                                    one_chip)
     compiled = jax.jit(kernel).lower(*args, *rep, valid).compile()
@@ -264,9 +263,8 @@ def test_four_chip_mesh_keeps_lanes_local(topo, no_cache, path):
         full = (*args, valid, *rep)
     elif path == "fleet_bucket":
         batched, replicated = _fleet_operands(FLEET_BUCKET)
-        kernel = functools.partial(
-            controller._controller_flat_fn, impl="pallas",
-            solve_cfg=autotune.DEFAULTS["sweep_solve"])
+        kernel = functools.partial(controller._controller_flat_fn,
+                                   impl="pallas")
         lanes = lambda nd: NamedSharding(
             mesh, PartitionSpec("batch", *([None] * (nd - 1))))
         args, valid, rep = _lane_specs(batched, replicated, lanes, rep_sh)
@@ -275,8 +273,7 @@ def test_four_chip_mesh_keeps_lanes_local(topo, no_cache, path):
     else:
         batched, replicated = _fleet_operands(FLEET_BUCKET)
         kernel = dispatch._f64_as_words(functools.partial(
-            controller._controller_flat_fn, impl="pallas",
-            solve_cfg=autotune.DEFAULTS["sweep_solve"]))
+            controller._controller_flat_fn, impl="pallas"))
         lanes = lambda nd: NamedSharding(
             mesh, PartitionSpec(None, "batch", *([None] * (nd - 2))))
         args, valid, rep = _lane_specs(batched, replicated, lanes, rep_sh,
